@@ -23,6 +23,8 @@ def main() -> int:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import decode_scaling, figure1, mass_serving, \
         roofline, table1
 

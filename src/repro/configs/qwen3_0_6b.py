@@ -1,6 +1,6 @@
 """qwen3-0.6b — small dense GQA transformer with qk_norm.
 
-[hf:Qwen/Qwen3-8B; hf] 28L d_model=1024 16H (GQA kv=8) d_ff=3072
+[hf:Qwen/Qwen3-0.6B; hf] 28L d_model=1024 16H (GQA kv=8) d_ff=3072
 vocab=151936. Qwen3 convention: head_dim 128, tied embeddings.
 """
 

@@ -2,9 +2,9 @@
 
 The serving hot path used to dispatch one kernel per generated token and
 round-trip the (Dk, Dv) state through HBM every step. These kernels run
-``W`` decode steps over a block of BH heads in ONE launch:
+``W`` decode steps over a block of heads in ONE launch:
 
-* grid = (BH // block_bh, W) with the token axis minor, so TPU iterates
+* grid = (N // block_bh, W) with the token axis minor, so TPU iterates
   the W steps sequentially per head-block program — the same
   sequential-grid carry trick as the chunked prefill kernels, at token
   granularity;
@@ -14,20 +14,26 @@ round-trip the (Dk, Dv) state through HBM every step. These kernels run
   instead of per token;
 * the HBM state buffer is updated in place via input/output aliasing —
   the W-step generalisation of the ``kernels/lookup`` decode trick,
-  extended from one head to the full (BH,) extent.
+  extended from one head to the full (N,) extent.
+
+Token rows are laid out token-major, (W, N, D): the block a grid step
+reads is a (block_bh, D) slab of one token, whose last two dimensions
+meet the TPU's (8, 128) tiling. A head-major (N, W, D) layout would need
+a (block_bh, 1, D) block, which Mosaic refuses (a 1 in the second-minor
+position). ``ops.py`` keeps the (B, H, W, D) interface and transposes.
 
 Heads are blocked rather than one-per-program because a decode step is a
 rank-1 update — an M=1 matmul that would waste the 128×128 MXU — so the
 update runs as batched VPU outer-products/reductions over ``block_bh``
 heads at once, and the grid stays small (which also keeps the
 interpret-mode CPU fallback cheap: kernel-body executions scale with
-W · BH/block_bh, not W · BH).
+W · N/block_bh, not W · N).
 
-Three variants share the structure:
+Three recurrences share one kernel body:
 
-  ``decode_linear``             S ← S + k vᵀ ;               o = Sᵀ q
-  ``decode_linear`` (normalize) additionally z ← z + k ;     o /= q·z
-  ``decode_gated``              S ← diag(exp(g)) S + k vᵀ ;  o = Sᵀ q
+  linear              S ← S + k vᵀ ;               o = Sᵀ q
+  linear (normalize)  additionally z ← z + k ;     o /= q·z
+  gated               S ← diag(exp(g)) S + k vᵀ ;  o = Sᵀ q
 
 Every variant also has a **variable-length masked** form (``lens=...``):
 each of the N rows carries its own valid length, and at window step w a
@@ -49,286 +55,174 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.linear_attention import safe_denom
 
-# VMEM budget for the resident state block; block_bh is chosen so the
-# fp32 (block_bh, Dk, Dv) scratch stays under it (~¼ of a core's VMEM,
-# leaving room for the double-buffered q/k/v/o rows).
-_STATE_VMEM_BYTES = 4 * 2**20
+# block_bh is sized from a stated budget, and the scoped-VMEM limit is
+# passed to Mosaic explicitly (not left to the compiler's default).
+# v5e has 128 MiB of VMEM per core; the kernel asks for 32 MiB and gives
+# half of it to the f32 (block_bh, Dk, Dv)-sized buffers, which are live
+# at once: the state block double-buffered in and out (4), the resident
+# scratch (1), and the body's temporaries — decay product, outer
+# product, update, masked select, lookup product (5). The other half
+# holds the double-buffered token rows and Mosaic's internal scratch.
+_VMEM_LIMIT_BYTES = 32 * 2**20
+_STATE_BUFFERS = 10
+_STATE_BUDGET_BYTES = _VMEM_LIMIT_BYTES // 2
+# second-minor tile of the (block_bh, D) token rows: 8 sublanes in f32,
+# 16 for packed 16-bit rows
+_ROW_TILE = 16
 
 
 def _block_bh(n: int, dk: int, dv: int) -> int:
-    """Largest divisor of n whose state block fits the VMEM budget."""
-    cap = max(1, _STATE_VMEM_BYTES // (dk * dv * 4))
-    b = min(n, cap)
-    while n % b:
-        b -= 1
-    return b
+    """Largest divisor of n that is a multiple of the row tile and whose
+    state-sized buffers fit the budget; n itself when n fits or has no
+    such divisor (a block equal to the whole axis is always legal)."""
+    cap = max(1, _STATE_BUDGET_BYTES // (_STATE_BUFFERS * dk * dv * 4))
+    if n <= cap:
+        return n
+    for b in range(cap - cap % _ROW_TILE, 0, -_ROW_TILE):
+        if n % b == 0:
+            return b
+    return n
 
 
-def _rank1_update(s, k, v):
-    """Batched rank-1 state update. s: (N, Dk, Dv); k: (N, Dk);
-    v: (N, Dv)."""
-    return s + k[:, :, None] * v[:, None, :]
-
-
-def _lookup(s, q):
-    """o = Sᵀ q per head. s: (N, Dk, Dv); q: (N, Dk) → (N, Dv)."""
-    return jnp.sum(s * q[:, :, None], axis=1)
-
-
-def _linear_kernel(s_ref, q_ref, k_ref, v_ref, o_ref, s_out_ref,
-                   s_scratch):
+def _kernel(*refs, varlen, normalize, gated, eps):
+    """One decode step of one head block: refs are, in order, [lens],
+    s, [z], q, k, v, [g] (inputs), o, s_out, [z_out] (outputs), and the
+    s [, z] VMEM scratch — bracketed ones only for the variant that
+    uses them."""
+    refs = list(refs)
+    lens_ref = refs.pop(0) if varlen else None
+    s_ref = refs.pop(0)
+    z_ref = refs.pop(0) if normalize else None
+    q_ref, k_ref, v_ref = refs.pop(0), refs.pop(0), refs.pop(0)
+    g_ref = refs.pop(0) if gated else None
+    o_ref, s_out_ref = refs.pop(0), refs.pop(0)
+    z_out_ref = refs.pop(0) if normalize else None
+    s_scratch = refs.pop(0)
+    z_scratch = refs.pop(0) if normalize else None
     w = pl.program_id(1)
 
     @pl.when(w == 0)
     def _load():
         s_scratch[...] = s_ref[...].astype(jnp.float32)
+        if normalize:
+            z_scratch[...] = z_ref[...].astype(jnp.float32)
 
-    q = q_ref[:, 0].astype(jnp.float32)          # (N, Dk)
-    k = k_ref[:, 0].astype(jnp.float32)
-    v = v_ref[:, 0].astype(jnp.float32)          # (N, Dv)
-    s = _rank1_update(s_scratch[...], k, v)
+    q = q_ref[...].astype(jnp.float32)           # (bn, Dk)
+    k = k_ref[...].astype(jnp.float32)           # (bn, Dk)
+    v = v_ref[...].astype(jnp.float32)           # (bn, Dv)
+    s_prev = s_scratch[...]                      # (bn, Dk, Dv)
+    decayed = s_prev
+    if gated:
+        a = jnp.exp(g_ref[...].astype(jnp.float32))      # (bn, Dk)
+        decayed = a[:, :, None] * s_prev
+    s = decayed + k[:, :, None] * v[:, None, :]
+    valid = lens_ref[...] > w if varlen else None        # (bn, 1) bool
+    if varlen:
+        s = jnp.where(valid[:, :, None], s, s_prev)
     s_scratch[...] = s
-    o_ref[:, 0] = _lookup(s, q).astype(o_ref.dtype)
+    o = jnp.sum(s * q[:, :, None], axis=1)               # (bn, Dv)
+    if normalize:
+        z_prev = z_scratch[...]
+        z = z_prev + k
+        if varlen:
+            z = jnp.where(valid, z, z_prev)
+        z_scratch[...] = z
+        # shared sign-preserving clamp: kernel-vs-reference equality is
+        # the acceptance check, so the denominators must be the same
+        # formula
+        o = o / safe_denom(jnp.sum(q * z, axis=1), eps)[:, None]
+    if varlen:
+        o = jnp.where(valid, o, 0.0)
+    o_ref[...] = o.astype(o_ref.dtype)
 
     @pl.when(w == pl.num_programs(1) - 1)
     def _store():
         s_out_ref[...] = s_scratch[...].astype(s_out_ref.dtype)
+        if normalize:
+            z_out_ref[...] = z_scratch[...].astype(z_out_ref.dtype)
 
 
-def _linear_norm_kernel(s_ref, z_ref, q_ref, k_ref, v_ref,
-                        o_ref, s_out_ref, z_out_ref,
-                        s_scratch, z_scratch, *, eps):
-    w = pl.program_id(1)
+def _decode(s, q, k, v, *, z=None, g=None, lens=None, eps=1e-6,
+            interpret=False):
+    """Shared launcher. s: (N, Dk, Dv); q, k, g: (W, N, Dk);
+    v: (W, N, Dv); z: (N, Dk) or None; lens: (N,) or None. Returns
+    (o: (W, N, Dv), s_new, z_new) with s (and z) aliased in place."""
+    n, dk, dv = s.shape
+    w_steps = q.shape[0]
+    bn = _block_bh(n, dk, dv)
+    varlen, normalize, gated = lens is not None, z is not None, \
+        g is not None
 
-    @pl.when(w == 0)
-    def _load():
-        s_scratch[...] = s_ref[...].astype(jnp.float32)
-        z_scratch[...] = z_ref[...].astype(jnp.float32)
+    def row(dim):
+        return pl.BlockSpec((None, bn, dim), lambda b, w: (w, b, 0))
 
-    q = q_ref[:, 0].astype(jnp.float32)
-    k = k_ref[:, 0].astype(jnp.float32)
-    v = v_ref[:, 0].astype(jnp.float32)
-    s = _rank1_update(s_scratch[...], k, v)
-    z = z_scratch[...] + k                       # (N, Dk)
-    s_scratch[...] = s
-    z_scratch[...] = z
-    # shared sign-preserving clamp: kernel-vs-reference equality is the
-    # acceptance check, so the denominators must be the same formula
-    denom = safe_denom(jnp.sum(q * z, axis=1), eps)    # (N,)
-    o_ref[:, 0] = (_lookup(s, q) / denom[:, None]).astype(o_ref.dtype)
-
-    @pl.when(w == pl.num_programs(1) - 1)
-    def _store():
-        s_out_ref[...] = s_scratch[...].astype(s_out_ref.dtype)
-        z_out_ref[...] = z_scratch[...].astype(z_out_ref.dtype)
-
-
-def _gated_kernel(s_ref, q_ref, k_ref, v_ref, g_ref, o_ref, s_out_ref,
-                  s_scratch):
-    w = pl.program_id(1)
-
-    @pl.when(w == 0)
-    def _load():
-        s_scratch[...] = s_ref[...].astype(jnp.float32)
-
-    q = q_ref[:, 0].astype(jnp.float32)
-    k = k_ref[:, 0].astype(jnp.float32)
-    v = v_ref[:, 0].astype(jnp.float32)
-    a = jnp.exp(g_ref[:, 0].astype(jnp.float32))  # (N, Dk)
-    s = _rank1_update(a[:, :, None] * s_scratch[...], k, v)
-    s_scratch[...] = s
-    o_ref[:, 0] = _lookup(s, q).astype(o_ref.dtype)
-
-    @pl.when(w == pl.num_programs(1) - 1)
-    def _store():
-        s_out_ref[...] = s_scratch[...].astype(s_out_ref.dtype)
-
-
-def _linear_varlen_kernel(lens_ref, s_ref, q_ref, k_ref, v_ref,
-                          o_ref, s_out_ref, s_scratch):
-    w = pl.program_id(1)
-
-    @pl.when(w == 0)
-    def _load():
-        s_scratch[...] = s_ref[...].astype(jnp.float32)
-
-    valid = lens_ref[...] > w                    # (N, 1) bool
-    q = q_ref[:, 0].astype(jnp.float32)          # (N, Dk)
-    k = k_ref[:, 0].astype(jnp.float32)
-    v = v_ref[:, 0].astype(jnp.float32)          # (N, Dv)
-    s_prev = s_scratch[...]
-    s = jnp.where(valid[:, :, None], _rank1_update(s_prev, k, v), s_prev)
-    s_scratch[...] = s
-    o_ref[:, 0] = jnp.where(valid, _lookup(s, q), 0.0).astype(o_ref.dtype)
-
-    @pl.when(w == pl.num_programs(1) - 1)
-    def _store():
-        s_out_ref[...] = s_scratch[...].astype(s_out_ref.dtype)
-
-
-def _linear_norm_varlen_kernel(lens_ref, s_ref, z_ref, q_ref, k_ref,
-                               v_ref, o_ref, s_out_ref, z_out_ref,
-                               s_scratch, z_scratch, *, eps):
-    w = pl.program_id(1)
-
-    @pl.when(w == 0)
-    def _load():
-        s_scratch[...] = s_ref[...].astype(jnp.float32)
-        z_scratch[...] = z_ref[...].astype(jnp.float32)
-
-    valid = lens_ref[...] > w                    # (N, 1) bool
-    q = q_ref[:, 0].astype(jnp.float32)
-    k = k_ref[:, 0].astype(jnp.float32)
-    v = v_ref[:, 0].astype(jnp.float32)
-    s_prev = s_scratch[...]
-    z_prev = z_scratch[...]
-    s = jnp.where(valid[:, :, None], _rank1_update(s_prev, k, v), s_prev)
-    z = jnp.where(valid, z_prev + k, z_prev)     # (N, Dk)
-    s_scratch[...] = s
-    z_scratch[...] = z
-    denom = safe_denom(jnp.sum(q * z, axis=1), eps)    # (N,)
-    o = _lookup(s, q) / denom[:, None]
-    o_ref[:, 0] = jnp.where(valid, o, 0.0).astype(o_ref.dtype)
-
-    @pl.when(w == pl.num_programs(1) - 1)
-    def _store():
-        s_out_ref[...] = s_scratch[...].astype(s_out_ref.dtype)
-        z_out_ref[...] = z_scratch[...].astype(z_out_ref.dtype)
-
-
-def _gated_varlen_kernel(lens_ref, s_ref, q_ref, k_ref, v_ref, g_ref,
-                         o_ref, s_out_ref, s_scratch):
-    w = pl.program_id(1)
-
-    @pl.when(w == 0)
-    def _load():
-        s_scratch[...] = s_ref[...].astype(jnp.float32)
-
-    valid = lens_ref[...] > w                    # (N, 1) bool
-    q = q_ref[:, 0].astype(jnp.float32)
-    k = k_ref[:, 0].astype(jnp.float32)
-    v = v_ref[:, 0].astype(jnp.float32)
-    a = jnp.exp(g_ref[:, 0].astype(jnp.float32))  # (N, Dk)
-    s_prev = s_scratch[...]
-    s = jnp.where(valid[:, :, None],
-                  _rank1_update(a[:, :, None] * s_prev, k, v), s_prev)
-    s_scratch[...] = s
-    o_ref[:, 0] = jnp.where(valid, _lookup(s, q), 0.0).astype(o_ref.dtype)
-
-    @pl.when(w == pl.num_programs(1) - 1)
-    def _store():
-        s_out_ref[...] = s_scratch[...].astype(s_out_ref.dtype)
-
-
-def _row(bn, dim):
-    """One (bn, 1, dim) token row of a (N, W, dim) input."""
-    return pl.BlockSpec((bn, 1, dim), lambda b, w: (b, w, 0))
-
-
-def _state(bn, dk, dv):
-    """The (bn, dk, dv) state block — same block at every w, touched
-    only at the grid edges."""
-    return pl.BlockSpec((bn, dk, dv), lambda b, w: (b, 0, 0))
-
-
-def _lens_spec(bn):
-    """The (bn, 1) per-row valid-length block — same block at every w."""
-    return pl.BlockSpec((bn, 1), lambda b, w: (b, 0))
+    state = pl.BlockSpec((bn, dk, dv), lambda b, w: (b, 0, 0))
+    vec = pl.BlockSpec((bn, dk), lambda b, w: (b, 0))
+    args, in_specs = [], []
+    if varlen:
+        args.append(lens.astype(jnp.int32).reshape(n, 1))
+        in_specs.append(pl.BlockSpec((bn, 1), lambda b, w: (b, 0)))
+    aliases = {len(args): 1}
+    args.append(s)
+    in_specs.append(state)
+    if normalize:
+        aliases[len(args)] = 2
+        args.append(z)
+        in_specs.append(vec)
+    args += [q, k, v]
+    in_specs += [row(dk), row(dk), row(dv)]
+    if gated:
+        args.append(g)
+        in_specs.append(row(dk))
+    out_specs = [row(dv), state]
+    out_shape = [jax.ShapeDtypeStruct((w_steps, n, dv), v.dtype),
+                 jax.ShapeDtypeStruct((n, dk, dv), s.dtype)]
+    scratch = [pltpu.VMEM((bn, dk, dv), jnp.float32)]
+    if normalize:
+        out_specs.append(vec)
+        out_shape.append(jax.ShapeDtypeStruct((n, dk), z.dtype))
+        scratch.append(pltpu.VMEM((bn, dk), jnp.float32))
+    outs = pl.pallas_call(
+        functools.partial(_kernel, varlen=varlen, normalize=normalize,
+                          gated=gated, eps=eps),
+        grid=(n // bn, w_steps),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        input_output_aliases=aliases,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(*args)
+    return outs[0], outs[1], (outs[2] if normalize else None)
 
 
 def decode_linear(s, q, k, v, *, z=None, normalize=False,
                   eps: float = 1e-6, lens=None, interpret: bool = False):
     """W fused decode steps of the plain linear recurrence.
 
-    s: (N, Dk, Dv); q, k: (N, W, Dk); v: (N, W, Dv); z: (N, Dk) or None.
+    s: (N, Dk, Dv); q, k: (W, N, Dk); v: (W, N, Dv); z: (N, Dk) or None.
     ``lens``: (N,) int32 per-row valid lengths — row n consumes only its
     first lens[n] window tokens (masked steps are inert; lens=0 rows are
-    untouched bit-for-bit). Returns (o: (N, W, Dv), s_new, z_new) with s
+    untouched bit-for-bit). Returns (o: (W, N, Dv), s_new, z_new) with s
     (and z) updated in place via input/output aliasing.
     """
-    n, dk, dv = s.shape
-    w_steps = q.shape[1]
-    bn = _block_bh(n, dk, dv)
-    grid = (n // bn, w_steps)
-    varlen = lens is not None
-    if varlen:
-        lens = lens.astype(jnp.int32).reshape(n, 1)
-    if not normalize:
-        kern = (_linear_varlen_kernel if varlen else _linear_kernel)
-        pre = (lens,) if varlen else ()
-        o, s_new = pl.pallas_call(
-            kern,
-            grid=grid,
-            in_specs=([_lens_spec(bn)] if varlen else [])
-            + [_state(bn, dk, dv), _row(bn, dk), _row(bn, dk),
-               _row(bn, dv)],
-            out_specs=[_row(bn, dv), _state(bn, dk, dv)],
-            out_shape=[
-                jax.ShapeDtypeStruct((n, w_steps, dv), v.dtype),
-                jax.ShapeDtypeStruct((n, dk, dv), s.dtype),
-            ],
-            scratch_shapes=[pltpu.VMEM((bn, dk, dv), jnp.float32)],
-            input_output_aliases={len(pre): 1},
-            interpret=interpret,
-        )(*pre, s, q, k, v)
-        return o, s_new, None
-
-    assert z is not None, "normalize=True needs the key-sum normaliser z"
-    zspec = pl.BlockSpec((bn, dk), lambda b, w: (b, 0))
-    kern = (functools.partial(_linear_norm_varlen_kernel, eps=eps)
-            if varlen else functools.partial(_linear_norm_kernel, eps=eps))
-    pre = (lens,) if varlen else ()
-    o, s_new, z_new = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=([_lens_spec(bn)] if varlen else [])
-        + [_state(bn, dk, dv), zspec, _row(bn, dk), _row(bn, dk),
-           _row(bn, dv)],
-        out_specs=[_row(bn, dv), _state(bn, dk, dv), zspec],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, w_steps, dv), v.dtype),
-            jax.ShapeDtypeStruct((n, dk, dv), s.dtype),
-            jax.ShapeDtypeStruct((n, dk), z.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bn, dk, dv), jnp.float32),
-            pltpu.VMEM((bn, dk), jnp.float32),
-        ],
-        input_output_aliases={len(pre): 1, len(pre) + 1: 2},
-        interpret=interpret,
-    )(*pre, s, z, q, k, v)
-    return o, s_new, z_new
+    assert not normalize or z is not None, \
+        "normalize=True needs the key-sum normaliser z"
+    return _decode(s, q, k, v, z=z if normalize else None, lens=lens,
+                   eps=eps, interpret=interpret)
 
 
 def decode_gated(s, q, k, v, g, *, lens=None, interpret: bool = False):
     """W fused decode steps of the gated recurrence (inclusive form).
 
-    s: (N, Dk, Dv); q, k, g: (N, W, Dk); v: (N, W, Dv). g is the
+    s: (N, Dk, Dv); q, k, g: (W, N, Dk); v: (W, N, Dv). g is the
     per-token log-decay (a = exp(g)); pass a broadcasted row for scalar
     per-head decay. ``lens``: (N,) int32 per-row valid lengths (masked
-    steps are inert — no decay, no update). Returns (o: (N, W, Dv),
+    steps are inert — no decay, no update). Returns (o: (W, N, Dv),
     s_new) with s updated in place via input/output aliasing.
     """
-    n, dk, dv = s.shape
-    w_steps = q.shape[1]
-    bn = _block_bh(n, dk, dv)
-    varlen = lens is not None
-    if varlen:
-        lens = lens.astype(jnp.int32).reshape(n, 1)
-    pre = (lens,) if varlen else ()
-    o, s_new = pl.pallas_call(
-        _gated_varlen_kernel if varlen else _gated_kernel,
-        grid=(n // bn, w_steps),
-        in_specs=([_lens_spec(bn)] if varlen else [])
-        + [_state(bn, dk, dv), _row(bn, dk), _row(bn, dk),
-           _row(bn, dv), _row(bn, dk)],
-        out_specs=[_row(bn, dv), _state(bn, dk, dv)],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, w_steps, dv), v.dtype),
-            jax.ShapeDtypeStruct((n, dk, dv), s.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((bn, dk, dv), jnp.float32)],
-        input_output_aliases={len(pre): 1},
-        interpret=interpret,
-    )(*pre, s, q, k, v, g)
+    o, s_new, _ = _decode(s, q, k, v, g=g, lens=lens, interpret=interpret)
     return o, s_new

@@ -1,9 +1,9 @@
 """Jit'd public wrappers for the fused W-step recurrent decode kernels.
 
-Handles (B, H, …) ↔ (BH, …) reshaping and the interpret-mode fallback
-used for CPU validation (the deployment target is TPU; on CPU the
-kernels run through the Pallas interpreter, so tests exercise the exact
-kernel code path).
+Handles the (B, H, W, D) ↔ token-major (W, B·H, D) layout change and
+the interpret-mode fallback used for CPU validation (the deployment
+target is TPU; on CPU the kernels run through the Pallas interpreter,
+so tests exercise the exact kernel code path).
 
 ``lens`` (a (B,) int32 vector of per-row valid window lengths) selects
 the variable-length masked kernels: row b advances only its first
@@ -26,6 +26,18 @@ Array = jax.Array
 
 def _on_cpu() -> bool:
     return jax.default_backend() == "cpu"
+
+
+def _tokens_major(x: Array) -> Array:
+    """(B, H, W, D) → the kernels' token-major (W, B·H, D) layout."""
+    b, h, w, d = x.shape
+    return jnp.transpose(x, (2, 0, 1, 3)).reshape(w, b * h, d)
+
+
+def _heads_major(o: Array, b: int, h: int) -> Array:
+    """(W, B·H, D) kernel output → (B, H, W, D)."""
+    w, _, d = o.shape
+    return jnp.transpose(o.reshape(w, b, h, d), (1, 2, 0, 3))
 
 
 def _lens_bh(lens: Optional[Array], b: int, h: int) -> Optional[Array]:
@@ -62,15 +74,13 @@ def fused_recurrent_linear(
     dv = v.shape[-1]
     o, s_new, z_new = _k.decode_linear(
         s.reshape(b * h, dk, dv),
-        q.reshape(b * h, w, dk),
-        k.reshape(b * h, w, dk),
-        v.reshape(b * h, w, dv),
+        _tokens_major(q), _tokens_major(k), _tokens_major(v),
         z=None if z is None else z.reshape(b * h, dk),
         normalize=normalize, eps=eps, lens=_lens_bh(lens, b, h),
         interpret=interpret,
     )
     return (
-        o.reshape(b, h, w, dv),
+        _heads_major(o, b, h),
         s_new.reshape(b, h, dk, dv),
         None if z_new is None else z_new.reshape(b, h, dk),
     )
@@ -99,11 +109,9 @@ def fused_recurrent_gated(
     dv = v.shape[-1]
     o, s_new = _k.decode_gated(
         s.reshape(b * h, dk, dv),
-        q.reshape(b * h, w, dk),
-        k.reshape(b * h, w, dk),
-        v.reshape(b * h, w, dv),
-        g.reshape(b * h, w, dk),
+        _tokens_major(q), _tokens_major(k), _tokens_major(v),
+        _tokens_major(g),
         lens=_lens_bh(lens, b, h),
         interpret=interpret,
     )
-    return o.reshape(b, h, w, dv), s_new.reshape(b, h, dk, dv)
+    return _heads_major(o, b, h), s_new.reshape(b, h, dk, dv)

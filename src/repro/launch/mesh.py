@@ -16,18 +16,21 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """Arbitrary mesh (elastic re-mesh path, smoke meshes)."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh (elastic re-mesh path, smoke meshes). Every axis
+    is Auto: the layer code places arrays with sharding constraints,
+    which may only name Auto axes (``jax.make_mesh`` defaults to
+    Explicit)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def required_devices(multi_pod: bool = False) -> int:
@@ -39,4 +42,4 @@ def make_smoke_mesh(data: Optional[int] = None, model: int = 1) -> Mesh:
     n = jax.device_count()
     if data is None:
         data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

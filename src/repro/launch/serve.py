@@ -79,12 +79,14 @@ from __future__ import annotations
 import argparse
 import signal
 import time
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import lm
 from repro.sharding import Rules
 
@@ -261,8 +263,9 @@ def stream_fleet(args) -> int:
     return 0
 
 
-def stream(args) -> int:
-    """Continuous batching under a synthetic Poisson request stream."""
+def stream(args, engines: Optional[list] = None) -> int:
+    """Continuous batching under a synthetic Poisson request stream.
+    ``engines``, when given, receives the engine this run drives."""
     from repro.serving import DecodeEngine
 
     if getattr(args, "replicas", 1) > 1 and not getattr(
@@ -301,6 +304,8 @@ def stream(args) -> int:
             getattr(args, "prefix_cache", "off")],
         cache_bytes=getattr(args, "cache_bytes", 64 << 20),
         injector=injector)
+    if engines is not None:
+        engines.append(engine)
 
     if getattr(args, "recover", False):
         if engine.journal is None and engine._ckpt_mgr is None:
@@ -506,8 +511,9 @@ def retrieve(args) -> int:
     return 0
 
 
-def lookup(args) -> int:
-    """Memory-serving: ingest once, pin resident, serve query waves."""
+def lookup(args, engines: Optional[list] = None) -> int:
+    """Memory-serving: ingest once, pin resident, serve query waves.
+    ``engines``, when given, receives the engine this run drives."""
     from repro.qa.gru import gru_params
     from repro.serving import LookupEngine
 
@@ -521,6 +527,8 @@ def lookup(args) -> int:
         encoder, backend=args.lookup_backend, wave_size=args.wave_size,
         max_queue=getattr(args, "max_queue", None),
         shed_policy=getattr(args, "shed_policy", "reject_new"))
+    if engines is not None:
+        engines.append(engine)
 
     rng = np.random.default_rng(args.seed)
     if args.load:
@@ -572,7 +580,13 @@ def lookup(args) -> int:
     return 0
 
 
-def main() -> int:
+def main(argv: Optional[Sequence[str]] = None,
+         engines: Optional[list] = None) -> int:
+    """Parse ``argv`` (default: the command line) and run the mode.
+    ``engines``, when given, receives the engine a stream or lookup run
+    drives, so an in-process caller can inspect its programs and
+    counters afterwards."""
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", default="generate",
                     choices=["generate", "stream", "spec", "retrieve",
@@ -684,7 +698,7 @@ def main() -> int:
                     choices=["ngram", "model"],
                     help="draft provider: prompt-lookup n-grams (free) "
                          "or a second LM with its own slot states")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.mode == "lookup" and args.load and \
             args.lookup_backend != "linear":
         ap.error(
@@ -694,11 +708,11 @@ def main() -> int:
             f"hidden states resident and cannot pin compressed "
             f"memories (drop --load and ingest documents instead)")
     if args.mode == "stream":
-        return stream(args)
+        return stream(args, engines)
     if args.mode == "spec":
         return spec(args)
     if args.mode == "lookup":
-        return lookup(args)
+        return lookup(args, engines)
     return generate(args) if args.mode == "generate" else retrieve(args)
 
 

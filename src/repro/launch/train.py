@@ -27,6 +27,7 @@ from repro.optim import adamw, cosine_warmup, opt_state_specs
 from repro.runtime import TrainLoop, TrainLoopConfig, make_train_step
 from repro.runtime.steps import train_state_specs
 from repro.sharding import Rules, tree_specs
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_production_mesh, make_smoke_mesh
 
 
@@ -99,6 +100,7 @@ def build(args):
 
 
 def main() -> int:
+    use_compile_cache()
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(message)s")
     ap = argparse.ArgumentParser()

@@ -481,9 +481,6 @@ def attention_apply(
 # single-token / windowed decode
 # ---------------------------------------------------------------------------
 
-_FUSED_FALLBACK_WARNED = set()
-
-
 def _use_fused_decode(cfg: ModelConfig) -> bool:
     """Resolve ``cfg.decode_kernel``. "auto" picks the Pallas kernels on
     TPU only — they use pltpu VMEM scratch and the sequential minor-grid
@@ -491,9 +488,9 @@ def _use_fused_decode(cfg: ModelConfig) -> bool:
     everywhere else (on CPU Pallas would run under the slow interpreter;
     tests force "fused" to validate the kernel path via interpret mode).
 
-    ``decode_kernel="fused"`` forced on any other backend (GPU, …) would
-    try to lower the TPU-only kernels and crash; fall back to the
-    reference recurrence with a one-time warning instead.
+    ``decode_kernel="fused"`` forced on any other backend (GPU, …)
+    raises: the TPU-only kernels cannot lower there, and a silent switch
+    to the reference would hide which path ran.
     """
     if cfg.decode_kernel == "auto":
         return jax.default_backend() == "tpu"
@@ -502,15 +499,10 @@ def _use_fused_decode(cfg: ModelConfig) -> bool:
     platform = jax.default_backend()
     if platform in ("tpu", "cpu"):  # cpu: Pallas interpret mode
         return True
-    if platform not in _FUSED_FALLBACK_WARNED:
-        _FUSED_FALLBACK_WARNED.add(platform)
-        import warnings
-        warnings.warn(
-            f"decode_kernel='fused' requested but the {platform!r} "
-            "backend cannot lower the TPU Pallas decode kernels (VMEM "
-            "scratch / minor-grid carry); falling back to the jnp scan "
-            "reference recurrence.", RuntimeWarning, stacklevel=2)
-    return False
+    raise ValueError(
+        f"decode_kernel='fused' requested on platform {platform!r}, "
+        "which cannot lower the TPU Pallas decode kernels (VMEM scratch "
+        "/ minor-grid carry); use decode_kernel='auto' or 'reference'")
 
 
 def _recurrent_linear(s, q, k, v, z, cfg: ModelConfig, lens=None):

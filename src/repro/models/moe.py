@@ -208,7 +208,6 @@ def _ambient_mesh():
 
 def moe_apply_shard_map(p: Params, x: Array, cfg: ModelConfig,
                         rules: Rules) -> Tuple[Array, Array]:
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m = cfg.moe
@@ -303,11 +302,11 @@ def moe_apply_shard_map(p: Params, x: Array, cfg: ModelConfig,
             gathered * w[:, None], token_idx, num_segments=n_loc)
         return combined.reshape(nb, tb, d), aux.astype(jnp.float32)
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(None, None), w_spec, w_spec, w_spec_t),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
     if m.n_shared > 0:

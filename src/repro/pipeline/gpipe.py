@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
+from repro.launch.mesh import make_mesh
 from repro.models import blocks as B
 from repro.models import layers as L
 from repro.models.lm import _dtype, cast_params, cross_entropy
@@ -45,8 +46,7 @@ Array = jax.Array
 def make_pipeline_mesh(stages: int = 4, data: int = 4,
                        model: int = 16) -> Mesh:
     """(stage, data, model) — stages×data×model chips (4×4×16 = one pod)."""
-    return jax.make_mesh((stages, data, model),
-                         ("stage", "data", "model"))
+    return make_mesh((stages, data, model), ("stage", "data", "model"))
 
 
 def pipeline_compatible(cfg: ModelConfig, n_stages: int) -> bool:
@@ -56,28 +56,12 @@ def pipeline_compatible(cfg: ModelConfig, n_stages: int) -> bool:
             and reps % n_stages == 0)
 
 
-def _partial_auto_supported() -> bool:
-    """Partial-auto shard_map ("stage" manual, data/model auto) needs
-    jax.shard_map (0.5+); the pre-0.5 experimental ``auto=`` spelling is
-    rejected by the SPMD partitioner (manual-subgroup check)."""
-    return hasattr(jax, "shard_map")
-
-
 def _shard_map(f, mesh, in_specs, out_specs):
-    if _partial_auto_supported():
-        # "stage" is the only MANUAL axis; data/model stay auto (GSPMD
-        # keeps managing TP/SP/DP inside the stage body).
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             axis_names=frozenset({"stage"}),
-                             check_vma=False)
-    # Fallback: fully manual over the whole mesh. Stage collectives are
-    # unchanged; data/model compute runs replicated inside the stage body
-    # (correct, unoptimized) — gpipe_loss_fn nulls the inner rules so the
-    # body emits no sharding constraints into the manual region.
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    # "stage" is the only MANUAL axis; data/model stay auto (GSPMD
+    # keeps managing TP/SP/DP inside the stage body).
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs,
+                         axis_names=frozenset({"stage"}), check_vma=False)
 
 
 def gpipe_loss_fn(
@@ -99,8 +83,6 @@ def gpipe_loss_fn(
         f"{cfg.name}: pattern {pattern}×{reps}+{tail} not divisible "
         f"into {n_stages} pipeline stages")
     adt = _dtype(cfg.dtype)
-    if not _partial_auto_supported():
-        rules = Rules.null()  # see _shard_map: fully-manual fallback
 
     def stage_body(params_stack, shared, x):
         """Run this stage's layers on x (B_mb, T, D)."""

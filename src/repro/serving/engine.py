@@ -1305,11 +1305,7 @@ class DecodeEngine:
         masking) — they advance in :meth:`step_spec_round` instead.
         One device dispatch + one host sync."""
         run_active = self._active & (self._spec_k == 0)
-        toks, carry = self._segment(
-            self.params, self.state,
-            jnp.asarray(self._tok), jnp.asarray(self._pos),
-            jnp.asarray(run_active), jnp.asarray(self._remaining),
-            self._key)
+        toks, carry = self._segment(*self._segment_args(run_active))
         emitted = np.asarray(toks)                      # (S, W)
         self.state = carry["state"]
         # np.array (copy): views of device arrays are read-only and the
@@ -1333,6 +1329,19 @@ class DecodeEngine:
             self._slot_toks[slot].extend(int(t) for t in row[row != PAD_ID])
             if not self._active[slot]:                  # finished mid-segment
                 self._free_slot(slot)
+
+    def _segment_args(self, run_active: np.ndarray) -> tuple:
+        return (self.params, self.state, jnp.asarray(self._tok),
+                jnp.asarray(self._pos), jnp.asarray(run_active),
+                jnp.asarray(self._remaining), self._key)
+
+    def segment_program_text(self) -> str:
+        """Optimised HLO text of the decode-segment program that
+        :meth:`step_segment` dispatches, compiled for the engine's
+        current shapes (shows, e.g., whether the Pallas decode kernels
+        are in it)."""
+        return self._segment.lower(
+            *self._segment_args(self._active)).compile().as_text()
 
     def _free_slot(self, slot: int) -> None:
         req = self._slot_req[slot]

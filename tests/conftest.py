@@ -20,11 +20,10 @@ def _release_compiled_programs():
     """Drop jit caches between test modules.
 
     The suite compiles several hundred distinct XLA programs in one
-    process; on the CPU backend the accumulated JIT'd code eventually
-    segfaults inside ``backend_compile`` (deterministically, at the
-    N-th program — jaxlib 0.4.37). No single module comes near the
-    threshold, so releasing executables at module boundaries keeps the
-    live-program count bounded. Within-module cache-hit/jit-miss
+    process. Releasing executables at module boundaries keeps the number
+    of live CPU programs, and the memory they hold, bounded however the
+    suite grows (an older jaxlib segfaulted in ``backend_compile`` once
+    too many had accumulated). Within-module cache-hit/jit-miss
     accounting (admission tests) is unaffected.
     """
     yield

@@ -33,6 +33,8 @@ def test_sharded_loss_matches_single_device():
     the distribution layer must not change the math."""
     run_sub("""
 import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType
+AUTO2 = (AxisType.Auto,) * 2
 from repro.configs import get_smoke_config
 from repro.models import lm
 from repro.sharding import Rules, tree_specs
@@ -48,7 +50,7 @@ batch = {'tokens': tokens, 'labels': tokens}
 loss_ref, _ = jax.jit(
     lambda p, b: lm.lm_loss(p, b, cfg, Rules.null()))(params, batch)
 
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = jax.make_mesh((2, 4), ('data', 'model'), axis_types=AUTO2)
 rules = Rules.for_mesh(mesh)
 with mesh:
     loss_sh, _ = jax.jit(
@@ -64,6 +66,8 @@ def test_shard_map_moe_matches_einsum():
     run_sub("""
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType
+AUTO2 = (AxisType.Auto,) * 2
 from repro.configs import get_smoke_config
 from repro.models.moe import moe_params, moe_apply, moe_apply_shard_map
 from repro.sharding import Rules
@@ -71,7 +75,7 @@ from repro.sharding import Rules
 cfg = get_smoke_config('deepseek-moe-16b')
 cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
     cfg.moe, capacity_factor=8.0, n_experts=8, top_k=2))
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = jax.make_mesh((2, 4), ('data', 'model'), axis_types=AUTO2)
 rules = Rules.for_mesh(mesh)
 key = jax.random.PRNGKey(0)
 p = moe_params(key, cfg, jnp.float32)
@@ -97,6 +101,8 @@ def test_elastic_restore_across_meshes():
     run_sub("""
 import tempfile, os
 import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType
+AUTO2 = (AxisType.Auto,) * 2
 from repro.checkpoint import save_pytree, restore_on_mesh
 from repro.sharding import Rules
 
@@ -105,14 +111,14 @@ tree = {'w': jax.random.normal(key, (16, 8)),
         'emb': jax.random.normal(jax.random.fold_in(key, 1), (32, 8))}
 spec = {'w': ('fsdp', 'ffn'), 'emb': ('vocab', None)}
 
-mesh_a = jax.make_mesh((2, 4), ('data', 'model'))
+mesh_a = jax.make_mesh((2, 4), ('data', 'model'), axis_types=AUTO2)
 placed = jax.device_put(tree['w'], jax.sharding.NamedSharding(
     mesh_a, jax.sharding.PartitionSpec('data', 'model')))
 path = os.path.join(tempfile.mkdtemp(), 'ck')
 save_pytree(path, {'w': placed, 'emb': tree['emb']})
 
 for shape in ((4, 2), (8, 1), (1, 8)):
-    mesh_b = jax.make_mesh(shape, ('data', 'model'))
+    mesh_b = jax.make_mesh(shape, ('data', 'model'), axis_types=AUTO2)
     restored, _ = restore_on_mesh(path, tree, spec, mesh_b)
     np.testing.assert_array_equal(np.asarray(restored['w']),
                                   np.asarray(tree['w']))
@@ -128,6 +134,8 @@ def test_decode_sharded_matches_null_rules():
     with padded state heads)."""
     run_sub("""
 import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType
+AUTO2 = (AxisType.Auto,) * 2
 from repro.configs import get_smoke_config
 from repro.models import lm
 from repro.sharding import Rules
@@ -141,7 +149,7 @@ st0 = lm.init_decode_state(cfg, 8, max_len=16)
 ref, _ = jax.jit(lambda p, s, t: lm.decode_step(
     p, s, t, jnp.int32(0), cfg, Rules.null()))(params, st0, tok)
 
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = jax.make_mesh((2, 4), ('data', 'model'), axis_types=AUTO2)
 rules = Rules.for_mesh(mesh, overrides={'fsdp': None})
 st1 = lm.init_decode_state(cfg, 8, max_len=16, rules=rules)
 with mesh:

@@ -16,7 +16,6 @@ Acceptance contract of the engine (ISSUE 2):
 
 import argparse
 import dataclasses
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -375,6 +374,25 @@ class TestGenerateEdges:
             prompt_len=8, gen_len=12, temperature=0.0, seed=0)
         assert serve.stream(args) == 0
 
+    def test_serve_main_argv_hands_back_engine(self, monkeypatch):
+        """serve.main runs in-process from an argv and hands back the
+        engine it drove, whose segment program can be inspected."""
+        from repro.launch import serve
+        monkeypatch.setattr(serve, "use_compile_cache", lambda: "")
+        engines = []
+        assert serve.main(
+            ["--mode", "stream", "--arch", "yi-34b", "--smoke",
+             "--backend", "linear", "--slots", "2", "--segment-len", "4",
+             "--n-requests", "3", "--prompt-len", "8", "--gen-len", "8",
+             "--temperature", "0"], engines) == 0
+        (engine,) = engines
+        comps = engine.completions()
+        assert len(comps) == 3 and {c.status for c in comps} == {"ok"}
+        text = engine.segment_program_text()
+        assert "HloModule" in text
+        # "auto" picks the jnp reference off TPU: no Pallas kernel
+        assert "tpu_custom_call" not in text
+
 
 class TestMixedSpeculativePlain:
     """Mixing speculative and plain requests in ONE slot batch never
@@ -692,18 +710,18 @@ class TestDecodeNumerics:
 
     def test_fused_fallback_warns_off_tpu(self, monkeypatch):
         """decode_kernel='fused' on a backend that cannot lower the TPU
-        Pallas kernels falls back to the reference path with ONE
-        warning instead of crashing."""
+        Pallas kernels raises a ValueError naming the platform — no
+        silent switch to the reference path."""
         cfg = get_smoke_config("yi-34b").with_backend("linear")
         cfg = dataclasses.replace(cfg, decode_kernel="fused")
         monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
-        A._FUSED_FALLBACK_WARNED.discard("gpu")
-        with pytest.warns(RuntimeWarning, match="falling back"):
-            assert A._use_fused_decode(cfg) is False
-        with warnings.catch_warnings():
-            warnings.simplefilter("error")      # second call is silent
-            assert A._use_fused_decode(cfg) is False
-        A._FUSED_FALLBACK_WARNED.discard("gpu")
+        with pytest.raises(ValueError, match="'gpu'"):
+            A._use_fused_decode(cfg)
+        # "auto" and "reference" never raise: auto picks the reference
+        # recurrence off TPU
+        for kernel in ("auto", "reference"):
+            assert A._use_fused_decode(
+                dataclasses.replace(cfg, decode_kernel=kernel)) is False
         # cpu + tpu still take the kernel path
         monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
         assert A._use_fused_decode(cfg) is True
