@@ -79,7 +79,7 @@ from __future__ import annotations
 import argparse
 import signal
 import time
-from typing import Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -263,6 +263,48 @@ def stream_fleet(args) -> int:
     return 0
 
 
+class _Latency:
+    """Time to first token and inter-token gaps, from
+    ``DecodeEngine.progress()`` after each step, on the engine's clock
+    (time.perf_counter readings taken where tokens reached the host)."""
+
+    def __init__(self):
+        self.ttft: List[float] = []      # seconds, one per request
+        self.gaps: List[float] = []      # seconds per token, weighted by
+        self.counts: List[int] = []      # the tokens one read brought
+        self._last: Dict[int, tuple] = {}  # uid -> (tokens, t_tokens)
+
+    def observe(self, progress) -> None:
+        for uid, rp in progress.requests.items():
+            n, t = self._last.get(uid, (0, None))
+            if rp.tokens <= n or rp.t_tokens is None:
+                continue
+            if n == 0:
+                if rp.t_first_token is None or rp.t_submit is None:
+                    # restored by --recover: its earlier times are gone
+                    self._last[uid] = (rp.tokens, rp.t_tokens)
+                    continue
+                self.ttft.append(rp.t_first_token - rp.t_submit)
+                n, t = 1, rp.t_first_token
+            if rp.tokens > n:
+                self.gaps.append((rp.t_tokens - t) / (rp.tokens - n))
+                self.counts.append(rp.tokens - n)
+            self._last[uid] = (rp.tokens, rp.t_tokens)
+        for c in progress.done:
+            self._last.pop(c.uid, None)
+
+    def report(self) -> str:
+        if not self.ttft:
+            return "no request was served"
+        ttft = np.asarray(self.ttft) * 1e3
+        line = (f"TTFT p50 {np.percentile(ttft, 50):.1f} ms, "
+                f"p99 {np.percentile(ttft, 99):.1f} ms")
+        if self.gaps:
+            itl = np.repeat(np.asarray(self.gaps) * 1e3, self.counts)
+            line += f"; inter-token p99 {np.percentile(itl, 99):.2f} ms"
+        return line + " (engine clock, compiles included)"
+
+
 def stream(args, engines: Optional[list] = None) -> int:
     """Continuous batching under a synthetic Poisson request stream.
     ``engines``, when given, receives the engine this run drives."""
@@ -326,10 +368,12 @@ def stream(args, engines: Optional[list] = None) -> int:
             engine.submit(prompt, g, arrival=arrival, fork=fork)
 
     t0 = time.perf_counter()
+    lat = _Latency()
     with _Drainer() as drain:
         try:
             while engine.has_work() and not drain.requested:
                 engine.step("continuous")
+                lat.observe(engine.progress())
         except InjectedCrash as e:
             # simulated hard kill: NO drain, NO final checkpoint — the
             # journal + last periodic checkpoint are all recovery gets
@@ -366,8 +410,6 @@ def stream(args, engines: Optional[list] = None) -> int:
               f"zero_loss={'PASS' if zero_loss else 'FAIL'}")
 
     total = sum(len(c.tokens) for c in completions)
-    served = [c for c in completions if c.admitted_step >= 0]
-    lat = [c.finished_step - c.admitted_step for c in served]
     statuses = {}
     for c in completions:
         statuses[c.status] = statuses.get(c.status, 0) + 1
@@ -377,9 +419,8 @@ def stream(args, engines: Optional[list] = None) -> int:
           f"{dt:.2f} s ({total/dt:.0f} tok/s incl. compile)")
     st = engine.stats
     print(f"slot utilization {st.slot_utilization:.2f} over "
-          f"{st.segments} segments; mean latency "
-          f"{np.mean(lat):.0f} decode steps" if served else
-          "no request was served")
+          f"{st.segments} segments")
+    print(lat.report())
     print("status: " + " ".join(
         f"{k}={v}" for k, v in sorted(statuses.items())))
     if st.shed or st.preemptions or st.quarantined or st.degrade_transitions:

@@ -238,14 +238,16 @@ def block_decode(
         att = jnp.tanh(p["xgate"]).astype(att.dtype) * att
         st = state   # memory is static during decode
     else:
-        att, st = A.attention_decode(p["attn"], h1, state, pos, cfg,
-                                     rules, active=active)
+        with jax.named_scope("decode.attention"):
+            att, st = A.attention_decode(p["attn"], h1, state, pos, cfg,
+                                         rules, active=active)
     x = x + att
     h2 = L.apply_norm(cfg.norm, p["norm2"], x)
-    if _uses_moe(kind, cfg):
-        ff, _ = MOE.moe_apply(p["moe"], h2, cfg, rules)
-    else:
-        ff = L.mlp(p["mlp"], h2, cfg.act)
+    with jax.named_scope("decode.mlp"):
+        if _uses_moe(kind, cfg):
+            ff, _ = MOE.moe_apply(p["moe"], h2, cfg, rules)
+        else:
+            ff = L.mlp(p["mlp"], h2, cfg.act)
     return x + ff, st
 
 

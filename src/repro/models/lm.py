@@ -367,21 +367,26 @@ def decode_step(
             new_states.append(st)
         return x, tuple(new_states)
 
-    x, new_stack = jax.lax.scan(
-        unit, x, (params["stack"], state["stack"]), length=reps)
+    # named scopes (decode.*) add op metadata only: a profiler trace
+    # attributes each device op to the layer scan, attention, MLP or head
+    with jax.named_scope("decode.layers"):
+        x, new_stack = jax.lax.scan(
+            unit, x, (params["stack"], state["stack"]), length=reps)
 
-    new_tail = []
-    for i, kind in enumerate(tail):
-        x, st = B.block_decode(
-            kind, params["tail"][i] if kind != "shared_attn" else None,
-            x, state["tail"][i], pos, cfg, rules, shared=shared,
-            active=active)
-        new_tail.append(st)
+        new_tail = []
+        for i, kind in enumerate(tail):
+            x, st = B.block_decode(
+                kind, params["tail"][i] if kind != "shared_attn" else None,
+                x, state["tail"][i], pos, cfg, rules, shared=shared,
+                active=active)
+            new_tail.append(st)
 
-    x = L.apply_norm(cfg.norm, params["final_norm"], x)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = x @ head.astype(adt)
-    logits = constrain(logits, rules, "batch", "vocab")
+    with jax.named_scope("decode.head"):
+        x = L.apply_norm(cfg.norm, params["final_norm"], x)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        logits = x @ head.astype(adt)
+        logits = constrain(logits, rules, "batch", "vocab")
     return logits, {"stack": new_stack, "tail": tuple(new_tail)}
 
 
@@ -755,11 +760,12 @@ def generate_segment(
         # not the whole cache — the row-level slot-masking optimisation)
         logits, st = decode_step(params, st, tok, pos, cfg, rules,
                                  active=act)
-        if greedy:
-            sub = None          # no PRNG consumed in the hot loop
-        else:
-            k, sub = jax.random.split(k)
-        nxt = sample_token(logits, temperature, sub)
+        with jax.named_scope("decode.head"):
+            if greedy:
+                sub = None          # no PRNG consumed in the hot loop
+            else:
+                k, sub = jax.random.split(k)
+            nxt = sample_token(logits, temperature, sub)
         emitted = jnp.where(act, nxt, pad_id)
         rem = jnp.where(act, rem - 1, rem)
         done = rem <= 0

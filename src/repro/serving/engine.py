@@ -108,8 +108,10 @@ from __future__ import annotations
 import bisect
 import dataclasses
 import functools
+import itertools
 import json
-from typing import Any, Dict, List, Optional, Tuple
+import time
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -141,9 +143,23 @@ from repro.serving.lifecycle import (
     SuspendedRequest,
     poison_snapshot,
 )
+from repro.serving.tracing import (
+    ADMIT,
+    INGEST,
+    LIFECYCLE,
+    POST,
+    SEGMENT,
+    WAIT,
+)
 from repro.sharding import Rules
 
 PAD_ID = -1  # emitted by masked slots; never a vocabulary id
+_span = jax.profiler.TraceAnnotation   # see repro.serving.tracing
+
+
+def _time_field():
+    """A host time: not compared, printed or journaled."""
+    return dataclasses.field(default=None, compare=False, repr=False)
 
 
 def _pow2_ceil(n: int) -> int:
@@ -171,6 +187,11 @@ class Request:
     priority: int = 0
     deadline_s: Optional[float] = None
     fork: int = 1
+    # engine-clock (time.perf_counter) times, see DecodeEngine.progress
+    t_submit: Optional[float] = _time_field()
+    t_first_chunk: Optional[float] = _time_field()
+    t_first_token: Optional[float] = _time_field()
+    t_tokens: Optional[float] = _time_field()
 
 
 @dataclasses.dataclass
@@ -183,6 +204,36 @@ class Completion:
     finished_step: int
     status: str = STATUS_OK       # ok|cancelled|deadline|shed|failed
     retries: int = 0              # numeric-fault retries consumed
+    # the request's engine-clock times (None after journal recovery)
+    t_submit: Optional[float] = _time_field()
+    t_first_chunk: Optional[float] = _time_field()
+    t_first_token: Optional[float] = _time_field()
+    t_tokens: Optional[float] = _time_field()
+
+
+@dataclasses.dataclass
+class RequestProgress:
+    """One request as :meth:`DecodeEngine.progress` sees it: output
+    tokens so far, prompt tokens consumed, and engine-clock times
+    (``time.perf_counter``): submitted, first prompt chunk read back,
+    first token read back, latest tokens read back (None until then)."""
+    tokens: int
+    prompt_done: int
+    t_submit: Optional[float]
+    t_first_chunk: Optional[float]
+    t_first_token: Optional[float]
+    t_tokens: Optional[float]
+
+    @classmethod
+    def of(cls, r, tokens: int, prompt_done: int) -> "RequestProgress":
+        """From a :class:`Request` or a :class:`Completion`."""
+        return cls(tokens, prompt_done, r.t_submit, r.t_first_chunk,
+                   r.t_first_token, r.t_tokens)
+
+
+class Progress(NamedTuple):
+    requests: Dict[int, RequestProgress]
+    done: List[Completion]
 
 
 @dataclasses.dataclass
@@ -198,6 +249,11 @@ class EngineStats:
     ingest_chunks: int = 0        # decode_window_varlen ingest launches
     ingest_interleaved: int = 0   # ...issued while decode slots were live
     admission_dispatches: int = 0  # total admission-path device calls
+    # token positions the prefill and ingest dispatches compute (rows x
+    # bucket width: n_slots rows pool-wide, 1 for the batch-1 programs)
+    # and the prompt tokens they consume; cache-hit landings add neither
+    admission_token_slots: int = 0
+    admission_tokens: int = 0
     prefill_jit_misses: int = 0   # new admission program shapes compiled
     # speculative rounds
     spec_rounds: int = 0          # batched draft/verify rounds
@@ -591,6 +647,7 @@ class DecodeEngine:
         self._ingest_cursor = np.zeros((s,), np.int64)
         self._queue: List[Request] = []   # kept sorted by (arrival, uid)
         self._completions: Dict[int, Completion] = {}
+        self._n_progressed = 0    # completions progress() has handed out
         self._clock = 0
         self._next_uid = 0
         self._key = jax.random.PRNGKey(self._seed)
@@ -698,7 +755,8 @@ class DecodeEngine:
         req = Request(uid=uid, prompt=prompt,
                       max_new_tokens=max_new_tokens, arrival=arrival,
                       speculate_k=speculate_k, priority=priority,
-                      deadline_s=deadline_s, fork=fork)
+                      deadline_s=deadline_s, fork=fork,
+                      t_submit=time.perf_counter())
         if self.max_queue is not None and len(self._queue) >= self.max_queue:
             victim = self._pick_shed_victim(req)
             self._shed(victim)
@@ -802,7 +860,9 @@ class DecodeEngine:
             uid=req.uid, prompt_len=len(req.prompt),
             tokens=np.asarray(tokens, np.int32), finish_reason=reason,
             admitted_step=admitted_step, finished_step=self._clock,
-            status=status, retries=retries)
+            status=status, retries=retries, t_submit=req.t_submit,
+            t_first_chunk=req.t_first_chunk,
+            t_first_token=req.t_first_token, t_tokens=req.t_tokens)
         if self.journal is not None:
             # ack-ahead: the delivery record hits stable storage before
             # the completion becomes observable; a crash between the
@@ -848,9 +908,14 @@ class DecodeEngine:
             self.params, jnp.asarray(req.prompt)[None, :])
         self.stats.prefills += 1
         self.stats.admission_dispatches += 1
+        self.stats.admission_token_slots += len(req.prompt)
+        self.stats.admission_tokens += len(req.prompt)
         self._key, sub = jax.random.split(self._key)
-        tok0 = int(self.backend.sample_token(
-            logits, self.temperature, sub)[0])
+        with _span(WAIT):
+            tok0 = int(self.backend.sample_token(
+                logits, self.temperature, sub)[0])
+        req.t_first_chunk = req.t_first_token = req.t_tokens = \
+            time.perf_counter()
         hit_eos = self.eos_id is not None and tok0 == self.eos_id
         if req.max_new_tokens <= 1 or hit_eos:
             self._complete(req, [tok0], admitted_step=self._clock,
@@ -1191,8 +1256,13 @@ class DecodeEngine:
                 self.state = self._admit(self.state, st_req, slot)
                 self.stats.prefills += 1
                 self.stats.admission_dispatches += 2
+                self.stats.admission_token_slots += 1
+                self.stats.admission_tokens += 1
                 self._ingest_cursor[slot] = 1
-                self._finish_ingest(slot, np.asarray(logits)[0])
+                with _span(WAIT):
+                    logits = np.asarray(logits)
+                req.t_first_chunk = time.perf_counter()
+                self._finish_ingest(slot, logits[0])
             slots = [s for s in slots if s not in ones]
             if not slots:
                 return
@@ -1222,9 +1292,12 @@ class DecodeEngine:
                     self.params, self.state,
                     jnp.asarray(tokens[slot:slot + 1]),
                     jnp.asarray(lens[slot:slot + 1]), jnp.int32(slot))
+                with _span(WAIT):
+                    last1 = np.asarray(last1)
                 last = np.zeros((self.n_slots,) + last1.shape[1:],
-                                np.asarray(last1).dtype)
-                last[slot] = np.asarray(last1)[0]
+                                last1.dtype)
+                last[slot] = last1[0]
+                rows = 1
             else:
                 self._miss("prefill_varlen", width)
                 mask = np.zeros((self.n_slots,), bool)
@@ -1232,6 +1305,9 @@ class DecodeEngine:
                 last, self.state = self._prefill_varlen(
                     self.params, self.state, jnp.asarray(tokens),
                     jnp.asarray(lens), jnp.asarray(mask))
+                with _span(WAIT):
+                    last = np.asarray(last)
+                rows = self.n_slots
             self.stats.admission_batches += 1
             self.stats.prefills += len(slots)
             self.stats.prefill_dispatches += 1
@@ -1247,15 +1323,22 @@ class DecodeEngine:
             last, self.state = program(
                 self.params, self.state, jnp.asarray(tokens),
                 jnp.asarray(pos0), jnp.asarray(lens))
+            with _span(WAIT):
+                last = np.asarray(last)
+            rows = self.n_slots
             self.stats.ingest_chunks += 1
             self.stats.admission_dispatches += 1
             if self._active.any():
                 self.stats.ingest_interleaved += 1
+        self.stats.admission_token_slots += rows * width
+        self.stats.admission_tokens += int(lens.sum())
 
-        last = np.asarray(last)
+        t_read = time.perf_counter()
         for slot in slots:
             self._ingest_cursor[slot] += int(lens[slot])
             req = self._ingest_req[slot]
+            if req.t_first_chunk is None:
+                req.t_first_chunk = t_read
             cur = int(self._ingest_cursor[slot])
             # populate the prefix cache at every full-chunk boundary
             # the ingest crosses (degraded half-chunks land on these
@@ -1288,8 +1371,10 @@ class DecodeEngine:
         self._ingest_req[slot] = None
         self._ingest_cursor[slot] = 0
         self._key, sub = jax.random.split(self._key)
-        tok0 = int(self.backend.sample_token(
-            jnp.asarray(logits_row)[None], self.temperature, sub)[0])
+        with _span(WAIT):
+            tok0 = int(self.backend.sample_token(
+                jnp.asarray(logits_row)[None], self.temperature, sub)[0])
+        req.t_first_token = req.t_tokens = time.perf_counter()
         hit_eos = self.eos_id is not None and tok0 == self.eos_id
         if req.max_new_tokens <= 1 or hit_eos:
             self._complete(req, [tok0], admitted_step=self._clock,
@@ -1306,16 +1391,18 @@ class DecodeEngine:
         One device dispatch + one host sync."""
         run_active = self._active & (self._spec_k == 0)
         toks, carry = self._segment(*self._segment_args(run_active))
-        emitted = np.asarray(toks)                      # (S, W)
-        self.state = carry["state"]
         # np.array (copy): views of device arrays are read-only and the
         # scheduler mutates these per-slot on admission. Slots masked out
         # of this segment (speculative ones) come back with tok/pos/
         # remaining untouched, but their `active` flag must be restored.
-        self._tok = np.array(carry["tok"])
-        self._pos = np.array(carry["pos"])
-        self._remaining = np.array(carry["remaining"])
-        carried = np.array(carry["active"])
+        with _span(WAIT):
+            emitted = np.asarray(toks)                  # (S, W)
+            self._tok = np.array(carry["tok"])
+            self._pos = np.array(carry["pos"])
+            self._remaining = np.array(carry["remaining"])
+            carried = np.array(carry["active"])
+        t_read = time.perf_counter()
+        self.state = carry["state"]
         self._active = np.where(run_active, carried, self._active)
         self._key = carry["key"]
         self._clock += self.segment_len
@@ -1327,6 +1414,7 @@ class DecodeEngine:
                 continue
             row = emitted[slot]
             self._slot_toks[slot].extend(int(t) for t in row[row != PAD_ID])
+            self._slot_req[slot].t_tokens = t_read
             if not self._active[slot]:                  # finished mid-segment
                 self._free_slot(slot)
 
@@ -1507,7 +1595,9 @@ class DecodeEngine:
             occupied = self._active | np.asarray(
                 [r is not None for r in self._ingest_req])
             if occupied.any():
-                finite = np.asarray(self._finite(self.state))
+                finite = self._finite(self.state)
+                with _span(WAIT):
+                    finite = np.asarray(finite)
                 self.stats.finite_checks += 1
                 for slot in np.nonzero(occupied & ~finite
                                        & ~self._quarantined)[0]:
@@ -1904,6 +1994,7 @@ class DecodeEngine:
             self.params, state_pre, jnp.asarray(window),
             jnp.asarray(self._pos))
         greedy = np.asarray(greedy)                     # (S, w+1)
+        t_read = time.perf_counter()
         # chaos hook: a sabotaged round accepts ZERO draft tokens, so
         # every continuing slot takes the rewind path. The emitted token
         # is still g[0] — the target's own greedy next token — so the
@@ -1940,6 +2031,7 @@ class DecodeEngine:
                     finished = True
                     break
             self._slot_toks[slot].extend(emitted)
+            self._slot_req[slot].t_tokens = t_read
             self.stats.spec_emitted += len(emitted)
             max_emitted = max(max_emitted, len(emitted))
 
@@ -2018,6 +2110,39 @@ class DecodeEngine:
         """Completions recorded so far, in uid order."""
         return [self._completions[u] for u in sorted(self._completions)]
 
+    def progress(self) -> Progress:
+        """Each live request's progress, and the completions since the
+        last call (in the order they completed).
+
+        ``requests`` maps the uid of every request in a slot, decoding
+        or mid-prompt, and of every request in ``done``, to its
+        :class:`RequestProgress`. Suspended and queued requests are not
+        in it. The times are ``time.perf_counter`` readings the engine
+        took as it went: one at submit, one after the host read of each
+        admission or ingest dispatch, one after each first token's read,
+        and one after each segment's token read. Reading them here costs
+        nothing more."""
+        seen: Dict[int, RequestProgress] = {}
+        for slot in range(self.n_slots):
+            req = self._slot_req[slot]
+            if req is not None:
+                seen[req.uid] = RequestProgress.of(
+                    req, len(self._slot_toks[slot]), len(req.prompt))
+                continue
+            req = self._ingest_req[slot]
+            if req is not None:
+                seen[req.uid] = RequestProgress.of(
+                    req, 0, int(self._ingest_cursor[slot]))
+        done: List[Completion] = []
+        if len(self._completions) > self._n_progressed:
+            done = list(itertools.islice(self._completions.values(),
+                                         self._n_progressed, None))
+            self._n_progressed = len(self._completions)
+            for c in done:
+                seen[c.uid] = RequestProgress.of(c, len(c.tokens),
+                                                 c.prompt_len)
+        return Progress(seen, done)
+
     def step(self, policy: str = "continuous") -> bool:
         """ONE outer scheduling iteration: lifecycle pass (cancels,
         deadlines, degradation), admission pass (preempt + resume +
@@ -2030,10 +2155,13 @@ class DecodeEngine:
         assert policy in ("continuous", "static"), policy
         if not self.has_work():
             return False
-        self._lifecycle_pass()
-        self._admit_pass(policy)
+        with _span(LIFECYCLE):
+            self._lifecycle_pass()
+        with _span(ADMIT):
+            self._admit_pass(policy)
         if self._any_ingesting():
-            self._ingest_step()
+            with _span(INGEST):
+                self._ingest_step()
         if not self._active.any():
             if self._any_ingesting():
                 return self.has_work()
@@ -2056,11 +2184,14 @@ class DecodeEngine:
                 self._clock += skip * self.segment_len
             return self.has_work()
         if (self._active & (self._spec_k == 0)).any():
-            self.step_segment()
-            self._post_event()
+            with _span(SEGMENT):
+                self.step_segment()
+            with _span(POST):
+                self._post_event()
         if (self._active & (self._spec_k > 0)).any():
             self.step_spec_round()
-            self._post_event()
+            with _span(POST):
+                self._post_event()
         return self.has_work()
 
     def run(self, policy: str = "continuous") -> List[Completion]:
