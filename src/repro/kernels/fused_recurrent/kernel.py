@@ -14,7 +14,14 @@ round-trip the (Dk, Dv) state through HBM every step. These kernels run
   instead of per token;
 * the HBM state buffer is updated in place via input/output aliasing —
   the W-step generalisation of the ``kernels/lookup`` decode trick,
-  extended from one head to the full (N,) extent.
+  extended from one head to the full (N,) extent;
+* the state may be a stack of L layers, (L, N, Dk, Dv), with the layer
+  to advance passed as a scalar-prefetch index: the state blocks' index
+  map selects that layer, so the kernel reads and writes only its
+  blocks and the rest of the stack stays where it is, untouched. A layer
+  scan can then carry the whole stack and hand it to every layer's
+  launch, with no per-layer slice or write-back around the kernel. An
+  unstacked state is the stack of one.
 
 Token rows are laid out token-major, (W, N, D): the block a grid step
 reads is a (block_bh, D) slab of one token, whose last two dimensions
@@ -84,11 +91,13 @@ def _block_bh(n: int, dk: int, dv: int) -> int:
     return n
 
 
-def _kernel(*refs, varlen, normalize, gated, eps):
-    """One decode step of one head block: refs are, in order, [lens],
-    s, [z], q, k, v, [g] (inputs), o, s_out, [z_out] (outputs), and the
-    s [, z] VMEM scratch — bracketed ones only for the variant that
-    uses them."""
+def _kernel(layer_ref, *refs, varlen, normalize, gated, eps):
+    """One decode step of one head block: ``layer_ref`` is the scalar-
+    prefetched layer index (read by the index maps only); refs are, in
+    order, [lens], s, [z], q, k, v, [g] (inputs), o, s_out, [z_out]
+    (outputs), and the s [, z] VMEM scratch — bracketed ones only for
+    the variant that uses them."""
+    del layer_ref
     refs = list(refs)
     lens_ref = refs.pop(0) if varlen else None
     s_ref = refs.pop(0)
@@ -142,31 +151,42 @@ def _kernel(*refs, varlen, normalize, gated, eps):
             z_out_ref[...] = z_scratch[...].astype(z_out_ref.dtype)
 
 
-def _decode(s, q, k, v, *, z=None, g=None, lens=None, eps=1e-6,
-            interpret=False):
-    """Shared launcher. s: (N, Dk, Dv); q, k, g: (W, N, Dk);
-    v: (W, N, Dv); z: (N, Dk) or None; lens: (N,) or None. Returns
-    (o: (W, N, Dv), s_new, z_new) with s (and z) aliased in place."""
-    n, dk, dv = s.shape
+def _decode(s, q, k, v, *, z=None, g=None, lens=None, layer=None,
+            eps=1e-6, interpret=False):
+    """Shared launcher. s: (N, Dk, Dv), or with ``layer`` the stacked
+    (L, N, Dk, Dv) states of L layers, of which only layer ``layer`` (a
+    traced int32 scalar) is read and advanced; q, k, g: (W, N, Dk);
+    v: (W, N, Dv); z: (N, Dk), or (L, N, Dk) with ``layer``, or None;
+    lens: (N,) or None. Returns (o: (W, N, Dv), s_new, z_new) with s
+    (and z) aliased in place: with ``layer``, the whole stack comes back
+    with that layer's blocks rewritten and every other byte untouched."""
+    stacked = layer is not None
+    if not stacked:
+        s = s[None]
+        z = None if z is None else z[None]
+        layer = 0
+    _, n, dk, dv = s.shape
     w_steps = q.shape[0]
     bn = _block_bh(n, dk, dv)
     varlen, normalize, gated = lens is not None, z is not None, \
         g is not None
 
     def row(dim):
-        return pl.BlockSpec((None, bn, dim), lambda b, w: (w, b, 0))
+        return pl.BlockSpec((None, bn, dim), lambda b, w, l: (w, b, 0))
 
-    state = pl.BlockSpec((bn, dk, dv), lambda b, w: (b, 0, 0))
-    vec = pl.BlockSpec((bn, dk), lambda b, w: (b, 0))
+    state = pl.BlockSpec((None, bn, dk, dv),
+                         lambda b, w, l: (l[0], b, 0, 0))
+    vec = pl.BlockSpec((None, bn, dk), lambda b, w, l: (l[0], b, 0))
+    # operand 0 is the scalar-prefetched layer index; aliases count it
     args, in_specs = [], []
     if varlen:
         args.append(lens.astype(jnp.int32).reshape(n, 1))
-        in_specs.append(pl.BlockSpec((bn, 1), lambda b, w: (b, 0)))
-    aliases = {len(args): 1}
+        in_specs.append(pl.BlockSpec((bn, 1), lambda b, w, l: (b, 0)))
+    aliases = {1 + len(args): 1}
     args.append(s)
     in_specs.append(state)
     if normalize:
-        aliases[len(args)] = 2
+        aliases[1 + len(args)] = 2
         args.append(z)
         in_specs.append(vec)
     args += [q, k, v]
@@ -176,53 +196,67 @@ def _decode(s, q, k, v, *, z=None, g=None, lens=None, eps=1e-6,
         in_specs.append(row(dk))
     out_specs = [row(dv), state]
     out_shape = [jax.ShapeDtypeStruct((w_steps, n, dv), v.dtype),
-                 jax.ShapeDtypeStruct((n, dk, dv), s.dtype)]
+                 jax.ShapeDtypeStruct(s.shape, s.dtype)]
     scratch = [pltpu.VMEM((bn, dk, dv), jnp.float32)]
     if normalize:
         out_specs.append(vec)
-        out_shape.append(jax.ShapeDtypeStruct((n, dk), z.dtype))
+        out_shape.append(jax.ShapeDtypeStruct(z.shape, z.dtype))
         scratch.append(pltpu.VMEM((bn, dk), jnp.float32))
     outs = pl.pallas_call(
         functools.partial(_kernel, varlen=varlen, normalize=normalize,
                           gated=gated, eps=eps),
-        grid=(n // bn, w_steps),
-        in_specs=in_specs,
-        out_specs=out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n // bn, w_steps),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch),
         out_shape=out_shape,
-        scratch_shapes=scratch,
         input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(*args)
-    return outs[0], outs[1], (outs[2] if normalize else None)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), *args)
+    o, s_new = outs[0], outs[1]
+    z_new = outs[2] if normalize else None
+    if not stacked:
+        s_new = s_new[0]
+        z_new = None if z_new is None else z_new[0]
+    return o, s_new, z_new
 
 
 def decode_linear(s, q, k, v, *, z=None, normalize=False,
-                  eps: float = 1e-6, lens=None, interpret: bool = False):
+                  eps: float = 1e-6, lens=None, layer=None,
+                  interpret: bool = False):
     """W fused decode steps of the plain linear recurrence.
 
     s: (N, Dk, Dv); q, k: (W, N, Dk); v: (W, N, Dv); z: (N, Dk) or None.
     ``lens``: (N,) int32 per-row valid lengths — row n consumes only its
     first lens[n] window tokens (masked steps are inert; lens=0 rows are
-    untouched bit-for-bit). Returns (o: (W, N, Dv), s_new, z_new) with s
+    untouched bit-for-bit). ``layer``: an int32 scalar, with s (and z)
+    the stacked (L, N, Dk, Dv) and (L, N, Dk) states — only that layer
+    is read and advanced. Returns (o: (W, N, Dv), s_new, z_new) with s
     (and z) updated in place via input/output aliasing.
     """
     assert not normalize or z is not None, \
         "normalize=True needs the key-sum normaliser z"
     return _decode(s, q, k, v, z=z if normalize else None, lens=lens,
-                   eps=eps, interpret=interpret)
+                   layer=layer, eps=eps, interpret=interpret)
 
 
-def decode_gated(s, q, k, v, g, *, lens=None, interpret: bool = False):
+def decode_gated(s, q, k, v, g, *, lens=None, layer=None,
+                 interpret: bool = False):
     """W fused decode steps of the gated recurrence (inclusive form).
 
     s: (N, Dk, Dv); q, k, g: (W, N, Dk); v: (W, N, Dv). g is the
     per-token log-decay (a = exp(g)); pass a broadcasted row for scalar
     per-head decay. ``lens``: (N,) int32 per-row valid lengths (masked
-    steps are inert — no decay, no update). Returns (o: (W, N, Dv),
-    s_new) with s updated in place via input/output aliasing.
+    steps are inert — no decay, no update). ``layer``: as in
+    :func:`decode_linear`, with s stacked (L, N, Dk, Dv). Returns
+    (o: (W, N, Dv), s_new) with s updated in place via input/output
+    aliasing.
     """
-    o, s_new, _ = _decode(s, q, k, v, g=g, lens=lens, interpret=interpret)
+    o, s_new, _ = _decode(s, q, k, v, g=g, lens=lens, layer=layer,
+                          interpret=interpret)
     return o, s_new

@@ -10,6 +10,11 @@ the variable-length masked kernels: row b advances only its first
 lens[b] tokens, masked steps are inert, and lens[b] = 0 leaves the row's
 state untouched bit-for-bit — ONE launch serves a batch of slots at
 different depths consuming different numbers of tokens.
+
+``layer`` (an int32 scalar) selects the stacked entry: ``s`` (and ``z``)
+then hold every layer's state, (L, B, H, Dk, Dv), and only that layer is
+read and advanced, in place in the stack — a layer scan carries the
+stack and hands it to each layer's launch whole.
 """
 
 from __future__ import annotations
@@ -58,31 +63,35 @@ def fused_recurrent_linear(
     normalize: bool = False,
     eps: float = 1e-6,
     lens: Optional[Array] = None,
+    layer: Optional[Array] = None,
     interpret: bool | None = None,
 ) -> Tuple[Array, Array, Optional[Array]]:
     """W fused decode steps, plain linear recurrence.
 
     s: (B, H, Dk, Dv); q, k: (B, H, W, Dk); v: (B, H, W, Dv);
     z: (B, H, Dk) or None; lens: (B,) int32 per-row valid lengths or
-    None (full window everywhere). Returns (o: (B, H, W, Dv), s_new,
-    z_new) with the state updated in place (input/output aliased) — one
-    kernel launch and one HBM state round-trip for the whole window.
+    None (full window everywhere); layer: None, or an int32 scalar with
+    s (L, B, H, Dk, Dv) and z (L, B, H, Dk) stacked. Returns
+    (o: (B, H, W, Dv), s_new, z_new) with the state updated in place
+    (input/output aliased) — one kernel launch and one HBM state
+    round-trip for the whole window.
     """
     if interpret is None:
         interpret = _on_cpu()
     b, h, w, dk = q.shape
     dv = v.shape[-1]
+    lead = s.shape[:-4]            # (L,) when stacked, else ()
     o, s_new, z_new = _k.decode_linear(
-        s.reshape(b * h, dk, dv),
+        s.reshape(lead + (b * h, dk, dv)),
         _tokens_major(q), _tokens_major(k), _tokens_major(v),
-        z=None if z is None else z.reshape(b * h, dk),
+        z=None if z is None else z.reshape(lead + (b * h, dk)),
         normalize=normalize, eps=eps, lens=_lens_bh(lens, b, h),
-        interpret=interpret,
+        layer=layer, interpret=interpret,
     )
     return (
         _heads_major(o, b, h),
-        s_new.reshape(b, h, dk, dv),
-        None if z_new is None else z_new.reshape(b, h, dk),
+        s_new.reshape(s.shape),
+        None if z_new is None else z_new.reshape(z.shape),
     )
 
 
@@ -94,13 +103,15 @@ def fused_recurrent_gated(
     g: Array,
     *,
     lens: Optional[Array] = None,
+    layer: Optional[Array] = None,
     interpret: bool | None = None,
 ) -> Tuple[Array, Array]:
     """W fused decode steps, gated (decay) recurrence, inclusive form.
 
     s: (B, H, Dk, Dv); q, k, g: (B, H, W, Dk); v: (B, H, W, Dv).
     g is the log-decay (state is scaled by exp(g) each step); lens:
-    (B,) int32 per-row valid lengths or None. Returns
+    (B,) int32 per-row valid lengths or None; layer: None, or an int32
+    scalar with s stacked (L, B, H, Dk, Dv). Returns
     (o: (B, H, W, Dv), s_new) with the state updated in place.
     """
     if interpret is None:
@@ -108,10 +119,10 @@ def fused_recurrent_gated(
     b, h, w, dk = q.shape
     dv = v.shape[-1]
     o, s_new = _k.decode_gated(
-        s.reshape(b * h, dk, dv),
+        s.reshape(s.shape[:-4] + (b * h, dk, dv)),
         _tokens_major(q), _tokens_major(k), _tokens_major(v),
         _tokens_major(g),
         lens=_lens_bh(lens, b, h),
-        interpret=interpret,
+        layer=layer, interpret=interpret,
     )
-    return _heads_major(o, b, h), s_new.reshape(b, h, dk, dv)
+    return _heads_major(o, b, h), s_new.reshape(s.shape)

@@ -505,29 +505,48 @@ def _use_fused_decode(cfg: ModelConfig) -> bool:
         "/ minor-grid carry); use decode_kernel='auto' or 'reference'")
 
 
-def _recurrent_linear(s, q, k, v, z, cfg: ModelConfig, lens=None):
+def decodes_in_place(state, cfg: ModelConfig) -> bool:
+    """Whether a layer scan carries this block state whole, as a stack,
+    for each layer's fused kernel to advance its own layer in place:
+    the linear family's matrix state (an ``AttnState`` holding ``s``)
+    under the fused decode kernel. Every other state — KV caches, Mamba,
+    RWKV, cross memory, anything under the jnp reference — is sliced
+    out per layer by the scan and written back."""
+    return (isinstance(state, AttnState) and state.s is not None
+            and _use_fused_decode(cfg))
+
+
+def _recurrent_linear(s, q, k, v, z, cfg: ModelConfig, lens=None,
+                      layer=None):
     """W-step linear decode recurrence behind ``cfg.decode_kernel``:
     the fused Pallas kernel (VMEM-resident state, in-place HBM update)
     or the jnp scan reference. Shapes: s (B,H,Dk,Dv); q,k (B,H,W,Dk);
     v (B,H,W,Dv); z (B,H,Dk)|None; lens (B,)|None per-row valid
-    lengths (varlen masked kernels)."""
+    lengths (varlen masked kernels). ``layer``: an int32 scalar with s
+    (and z) stacked over layers, (L,B,H,Dk,Dv) — the fused kernel's
+    in-place stacked entry."""
     from repro.kernels.fused_recurrent import ops as FR
     from repro.kernels.fused_recurrent import ref as FRref
     if _use_fused_decode(cfg):
         return FR.fused_recurrent_linear(
-            s, q, k, v, z=z, normalize=cfg.linear_normalize, lens=lens)
+            s, q, k, v, z=z, normalize=cfg.linear_normalize, lens=lens,
+            layer=layer)
+    assert layer is None, "a stacked state needs the fused decode kernel"
     return FRref.fused_recurrent_linear_ref(
         s, q, k, v, z=z, normalize=cfg.linear_normalize, lens=lens)
 
 
-def _recurrent_gated(s, q, k, v, g, cfg: ModelConfig, lens=None):
+def _recurrent_gated(s, q, k, v, g, cfg: ModelConfig, lens=None,
+                     layer=None):
     """W-step gated decode recurrence behind ``cfg.decode_kernel``.
-    Shapes: s (B,H,Dk,Dv); q,k,g (B,H,W,Dk); v (B,H,W,Dv);
-    lens (B,)|None."""
+    Shapes: s (B,H,Dk,Dv), or (L,B,H,Dk,Dv) with ``layer``;
+    q,k,g (B,H,W,Dk); v (B,H,W,Dv); lens (B,)|None."""
     from repro.kernels.fused_recurrent import ops as FR
     from repro.kernels.fused_recurrent import ref as FRref
     if _use_fused_decode(cfg):
-        return FR.fused_recurrent_gated(s, q, k, v, g, lens=lens)
+        return FR.fused_recurrent_gated(s, q, k, v, g, lens=lens,
+                                        layer=layer)
+    assert layer is None, "a stacked state needs the fused decode kernel"
     return FRref.fused_recurrent_gated_ref(s, q, k, v, g, lens=lens)
 
 
@@ -540,6 +559,7 @@ def attention_decode(
     rules: Rules,
     *,
     active: Optional[Array] = None,
+    layer: Optional[Array] = None,
 ) -> Tuple[Array, AttnState]:
     """One decode step. x: (B, D); pos: () current position, or (B,)
     per-sequence positions (continuous batching: each slot sits at its
@@ -549,12 +569,19 @@ def attention_decode(
     (the paper's constant-time lookup).
 
     ``active``: (B,) bool slot mask. An inactive row's state is frozen
-    bit-for-bit AT ROW GRANULARITY: the linear family selects its O(k²)
-    matrix (cheap either way), but the softmax baseline gates the ONE
-    written KV-cache row — reading the current row back and writing
-    where(active, new, current) — instead of a whole-(max_len) cache
-    select per step, which is what makes slot masking affordable for
-    the KV-cache backend at large max_len.
+    bit-for-bit AT ROW GRANULARITY: the linear family's fused kernel
+    leaves the row untouched in place (its varlen mask at lens = 0), the
+    jnp reference selects its O(k²) matrix after the step, and the
+    softmax baseline gates the ONE written KV-cache row — reading the
+    current row back and writing where(active, new, current) — instead
+    of a whole-(max_len) cache select per step, which is what makes slot
+    masking affordable for the KV-cache backend at large max_len.
+
+    ``layer``: an int32 scalar when ``state`` is the whole layer stack
+    of a linear-family block under the fused kernel
+    (:func:`decodes_in_place`): s (L, B, H, Dk, Dv) [, z (L, B, H, Dk)].
+    The kernel advances that layer in place in the stack, which comes
+    back whole.
     """
     b, _ = x.shape
     h, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -607,19 +634,25 @@ def attention_decode(
         if cfg.feature_gate:
             k2, v2 = _gate_kv(p, xt, kf[:, :, None], vt[:, :, None], cfg)
             kf, vt = k2[:, :, 0], v2[:, :, 0]
-        hp = state.s.shape[1]          # padded head count (≥ h)
+        hp = state.s.shape[-3]         # padded head count (≥ h)
         qh = _pad_head_dim(qf.reshape(b, h, dh), hp)
         kh = _pad_head_dim(jnp.broadcast_to(
             kf[:, None], (b, g, hkv, dh)).reshape(b, h, dh), hp)
         vh = _pad_head_dim(jnp.broadcast_to(
             vt[:, None], (b, g, hkv, dh)).reshape(b, h, dh), hp)
+        # the fused kernel freezes inactive rows itself (lens = 0 at
+        # W = 1); the reference selects after the step
+        fused = _use_fused_decode(cfg)
+        lens = None if active is None or not fused \
+            else active.astype(jnp.int32)
+        freeze = active is not None and not fused
 
         if backend == "linear":
             o_w, s_new, z_new = _recurrent_linear(
                 state.s, qh[:, :, None], kh[:, :, None], vh[:, :, None],
-                state.z, cfg)
+                state.z, cfg, lens=lens, layer=layer)
             o_h = o_w[:, :, 0]
-            if active is not None:  # O(k²) per-row freeze
+            if freeze:  # O(k²) per-row freeze
                 sel = active[:, None, None, None]
                 s_new = jnp.where(sel, s_new, state.s)
                 if z_new is not None:
@@ -633,12 +666,12 @@ def attention_decode(
             gd = _pad_head_dim(gd, hp)
             o_w, s_new = _recurrent_gated(
                 state.s, qh[:, :, None], kh[:, :, None], vh[:, :, None],
-                gd[:, :, None], cfg)
+                gd[:, :, None], cfg, lens=lens, layer=layer)
             o_h = o_w[:, :, 0]
             o_h = L.groupnorm_heads(
                 o_h[:, :h][:, None], p["gn_scale"].astype(jnp.float32),
                 p["gn_bias"].astype(jnp.float32))[:, 0]
-            if active is not None:  # O(k²) per-row freeze
+            if freeze:  # O(k²) per-row freeze
                 s_new = jnp.where(active[:, None, None, None],
                                   s_new, state.s)
             new_state = AttnState(k_cache=None, v_cache=None,

@@ -210,12 +210,16 @@ def block_decode(
     *,
     shared: Optional[Params] = None,
     active: Optional[Array] = None,
+    layer: Optional[Array] = None,
 ) -> Tuple[Array, Any]:
     """x: (B, D) one token per sequence; pos: () shared position or (B,)
     per-slot positions (continuous batching). ``active``: (B,) bool slot
     mask — inactive rows keep their state bit-for-bit (attention blocks
     mask at row granularity inside ``attention_decode``; other kinds via
-    a generic per-leaf select). Returns (x, new_state)."""
+    a generic per-leaf select). ``layer``: the block's index in its
+    layer stack when ``state`` is that whole stack
+    (``A.decodes_in_place``); the new stack comes back. Returns
+    (x, new_state)."""
     if kind == "shared_attn":
         p = shared
     if kind == "mamba":
@@ -240,7 +244,8 @@ def block_decode(
     else:
         with jax.named_scope("decode.attention"):
             att, st = A.attention_decode(p["attn"], h1, state, pos, cfg,
-                                         rules, active=active)
+                                         rules, active=active,
+                                         layer=layer)
     x = x + att
     h2 = L.apply_norm(cfg.norm, p["norm2"], x)
     with jax.named_scope("decode.mlp"):

@@ -339,7 +339,15 @@ def decode_step(
 
     Returns (logits (B, V), new_state). For the linear backends the cost
     is O(k²) per layer — independent of pos (paper's fast lookup).
+
+    A stacked block state that the fused kernel advances in place
+    (``A.decodes_in_place``: the linear family's matrix state) rides
+    through the layer scan as carry, whole, and each layer's kernel
+    reads and writes its own layer of it: no per-layer slice, write-back
+    or stack copy. Every other stacked state is scanned over as before.
     """
+    from repro.models.attention import decodes_in_place
+
     adt = _dtype(cfg.dtype)
     pattern, reps, tail = cfg.pattern_and_repeats
 
@@ -356,22 +364,36 @@ def decode_step(
     x = constrain(x, rules, "batch", "embed")
     shared = params["shared"]
 
-    def unit(x, scanned):
-        unit_params, unit_state = scanned
-        new_states = []
+    in_place = tuple(decodes_in_place(st, cfg) for st in state["stack"])
+    carried = tuple(st if ip else None
+                    for st, ip in zip(state["stack"], in_place))
+    scanned = tuple(None if ip else st
+                    for st, ip in zip(state["stack"], in_place))
+
+    def unit(carry, xs):
+        x, carried = carry
+        unit_params, unit_state, layer = xs
+        new_carried, new_states = [], []
         for p_i, kind in enumerate(pattern):
+            ip = in_place[p_i]
             x, st = B.block_decode(
                 kind, unit_params[p_i] if kind != "shared_attn" else None,
-                x, unit_state[p_i], pos, cfg, rules, shared=shared,
-                active=active)
-            new_states.append(st)
-        return x, tuple(new_states)
+                x, carried[p_i] if ip else unit_state[p_i], pos, cfg,
+                rules, shared=shared, active=active,
+                layer=layer if ip else None)
+            new_carried.append(st if ip else None)
+            new_states.append(None if ip else st)
+        return (x, tuple(new_carried)), tuple(new_states)
 
     # named scopes (decode.*) add op metadata only: a profiler trace
     # attributes each device op to the layer scan, attention, MLP or head
     with jax.named_scope("decode.layers"):
-        x, new_stack = jax.lax.scan(
-            unit, x, (params["stack"], state["stack"]), length=reps)
+        (x, carried), new_scanned = jax.lax.scan(
+            unit, (x, carried),
+            (params["stack"], scanned, jnp.arange(reps, dtype=jnp.int32)),
+            length=reps)
+        new_stack = tuple(c if ip else s for c, s, ip
+                          in zip(carried, new_scanned, in_place))
 
         new_tail = []
         for i, kind in enumerate(tail):

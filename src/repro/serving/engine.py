@@ -555,7 +555,10 @@ class DecodeEngine:
         def _admit(engine_state, request_state, slot):
             return be.write_slot_state(engine_state, request_state, slot)
 
-        @jax.jit
+        # the slot state is donated: the segment's loop updates it in
+        # place instead of copying it into its carry first, and
+        # step_segment replaces self.state with the carry it returns
+        @functools.partial(jax.jit, donate_argnums=(1,))
         def _segment(params, state, tok, pos, active, remaining, key):
             return be.generate_segment(
                 params, state, tok, pos, active, remaining, segment_len,

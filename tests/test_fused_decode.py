@@ -103,6 +103,49 @@ class TestFusedKernelMatchesSequential:
         assert s_f.shape == s.shape and s_f.dtype == s.dtype
 
 
+class TestStackedEntry:
+    """The stacked entry — the whole (L, B, H, Dk, Dv) stack and a layer
+    index — equals the unstacked kernel on that layer's slice, bit for
+    bit, and touches nothing else: not the inactive rows (lens = 0, the
+    slot freeze at W = 1), not the other layers."""
+
+    @pytest.mark.parametrize("layer", [0, 2])
+    @pytest.mark.parametrize("variant",
+                             ["linear", "linear_normalize", "gated"])
+    def test_matches_sliced_layer(self, key, variant, layer):
+        n_layers, b, h, d = 4, 3, 4, 128
+        ks = jax.random.split(key, 6)
+        s = jax.random.normal(ks[0], (n_layers, b, h, d, d))
+        z = jnp.abs(jax.random.normal(ks[1], (n_layers, b, h, d)))
+        q, k, v, _, _ = _qkv(ks[2], b, h, 1, d, d, positive=True)
+        g = -jax.nn.softplus(jax.random.normal(ks[3], (b, h, 1, d)))
+        lens = jnp.asarray([1, 0, 1], jnp.int32)   # row 1 inactive
+
+        def run(s_in, z_in, at):
+            if variant == "gated":
+                o, s_out = fr_ops.fused_recurrent_gated(
+                    s_in, q, k, v, g, lens=lens, layer=at, interpret=True)
+                return o, s_out, None
+            return fr_ops.fused_recurrent_linear(
+                s_in, q, k, v, z=z_in,
+                normalize=variant == "linear_normalize", lens=lens,
+                layer=at, interpret=True)
+
+        o, s_new, z_new = run(s, z, jnp.int32(layer))
+        o_1, s_1, z_1 = run(s[layer], z[layer], None)
+        assert s_new.shape == s.shape
+        np.testing.assert_array_equal(o, o_1)
+        np.testing.assert_array_equal(s_new[layer], s_1)
+        np.testing.assert_array_equal(s_new[layer, 1], s[layer, 1])
+        assert not np.array_equal(s_new[layer, 0], s[layer, 0])
+        others = np.asarray([i for i in range(n_layers) if i != layer])
+        np.testing.assert_array_equal(s_new[others], s[others])
+        if variant == "linear_normalize":
+            np.testing.assert_array_equal(z_new[layer], z_1)
+            np.testing.assert_array_equal(z_new[layer, 1], z[layer, 1])
+            np.testing.assert_array_equal(z_new[others], z[others])
+
+
 class TestModelWindowDecode:
     """lm.decode_window == W sequential lm.decode_step calls, with the
     Pallas kernels forced (decode_kernel="fused" → interpret on CPU)."""

@@ -252,6 +252,71 @@ class TestGenerateSegment:
                 bool(jnp.all(leaf[:, 2] == 0))
 
 
+class TestInPlaceSegment:
+    """Under the fused kernel the layer scan carries the stacked linear
+    state and each layer's kernel advances it in place, freezing
+    inactive slots itself; the engine donates the slot state to the
+    segment. Neither may change a token or leave a stale buffer behind."""
+
+    @staticmethod
+    def _engine(params, cfg, kernel):
+        return DecodeEngine(
+            params, dataclasses.replace(cfg, decode_kernel=kernel),
+            n_slots=2, segment_len=4, max_len=64)
+
+    @pytest.mark.parametrize("backend", ["linear", "gated_linear"])
+    def test_fused_equals_reference(self, key, backend):
+        """Admissions, slots frozen mid-segment and frees: the fused
+        (Pallas interpret) engine emits the reference engine's tokens."""
+        cfg = dataclasses.replace(
+            get_smoke_config("yi-34b").with_backend(backend),
+            dtype="float32")
+        params = lm.init_params(key, cfg)
+        prompts, gens = _make_workload(cfg)
+        outs = {}
+        for kernel in ("fused", "reference"):
+            eng = self._engine(params, cfg, kernel)
+            for p, g in zip(prompts, gens):
+                eng.submit(p, g)
+            outs[kernel] = eng.run("continuous")
+            assert eng.stats.prefills == len(prompts)
+            assert 0.0 < eng.stats.slot_utilization < 1.0
+        for a, b in zip(outs["fused"], outs["reference"]):
+            np.testing.assert_array_equal(a.tokens, b.tokens)
+
+    def test_buffers_valid_after_donated_segment(self, key):
+        """The pre-segment state is consumed by the segment; the probe,
+        a snapshot and a suspend/resume afterwards read the new state,
+        and the preempted request continues token for token."""
+        cfg = dataclasses.replace(
+            get_smoke_config("yi-34b").with_backend("linear"),
+            dtype="float32")
+        params = lm.init_params(key, cfg)
+        prompts, _ = _make_workload(cfg, n=2)
+        ref = self._engine(params, cfg, "fused")
+        for p in prompts:
+            ref.submit(p, 14)
+        want = {c.uid: c.tokens for c in ref.run("continuous")}
+
+        eng = self._engine(params, cfg, "fused")
+        for p in prompts:
+            eng.submit(p, 14)
+        eng.step()                      # admission, a segment, the probe
+        pre = eng.state
+        eng.step_segment()
+        assert all(leaf.is_deleted() for leaf in jax.tree.leaves(pre))
+        assert np.asarray(eng._finite(eng.state)).all()
+        snap = eng._snapshot(eng.state, jnp.int32(0))
+        assert all(np.isfinite(np.asarray(leaf)).all()
+                   for leaf in jax.tree.leaves(snap))
+        eng.preempt(1)
+        comps = eng.run("continuous")
+        assert eng.stats.preemptions == 1 and eng.stats.resumes == 1
+        assert sorted(c.uid for c in comps) == sorted(want)
+        for c in comps:
+            np.testing.assert_array_equal(c.tokens, want[c.uid])
+
+
 class TestSnapshotRestore:
     """snapshot_state / restore_state — the shared slot-slice primitive
     behind engine admission AND speculative rewind. Stacked leaves carry
