@@ -2,8 +2,9 @@
 
 After the window has closed and the engine is freed, a sample of the
 requests it finished, drawn from the seed and always holding the
-longest, is recomputed by the plain float32 reference over each prompt
-and its served tokens. The number compared is the widest gap by which a
+longest, is recomputed by the configuration family's plain float32
+reference (``bench/reference/<REFERENCE>.py``) over each prompt and its
+served tokens. The number compared is the widest gap by which a
 served (greedy) token's logit lies below the reference's best at its
 position; its limit is in ``bench/limits/<cell>.json``. Every request in
 the sample must also have finished ``ok`` with exactly the tokens it
@@ -16,7 +17,7 @@ from typing import Dict, List, Sequence
 
 import numpy as np
 
-from bench.reference import dense
+from bench import spec
 
 
 def sample(records: Sequence, seed: int, check: Dict) -> List:
@@ -39,6 +40,7 @@ def compare(params, conf: Dict, picked: Sequence, vocab_size: int,
     """Numbers the limits hold: ``logit_gap_max`` (the widest gap) and
     ``bad_requests`` (sampled requests that did not finish ``ok`` with
     their full, in-vocabulary output)."""
+    reference = spec.load_module("reference", spec.family(conf).REFERENCE)
     worst, bad, served = 0.0, 0, 0
     for r in picked:
         toks = np.asarray(r.tokens)
@@ -46,8 +48,8 @@ def compare(params, conf: Dict, picked: Sequence, vocab_size: int,
                 or toks.min() < 0 or toks.max() >= vocab_size):
             bad += 1
             continue
-        gaps = dense.served_gaps(params, conf, r.prompt, toks,
-                                 control=control)
+        gaps = reference.served_gaps(params, conf, r.prompt, toks,
+                                     control=control)
         served += len(gaps)
         worst = max(worst, float(gaps.max()))
     return {"logit_gap_max": worst, "bad_requests": float(bad),

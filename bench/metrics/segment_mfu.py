@@ -15,7 +15,7 @@ def read(run):
     secs = run.trace.module_s(["jit__segment"])
     if secs <= 0:
         return None
-    lm = run.work("dense_lm")
+    lm = run.work()
     n_tok, ctx = 0, 0
     for r in run.records:
         first, last = max(r.c_t0, 1), r.c_t1

@@ -37,7 +37,7 @@ import re
 import sys
 from collections import defaultdict
 from pathlib import Path
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 if __name__ == "__main__":
     _root = Path(__file__).resolve().parent.parent
@@ -407,6 +407,26 @@ def read_slice(path: Path):
     return planes, scopes, obj.get("counters", {})
 
 
+def load(trace_dir) -> Tuple[trace.TraceSummary, ProgramTrace, list,
+                             Dict[str, str]]:
+    """The profile under ``trace_dir`` (a traced run's) reduced over the
+    window ``bench/run.py`` measures: from the observation that opened
+    it to the one that closed it, each of which starts a
+    ``client.harvest`` span. Returns the client's reduction, the
+    program's, the planes and the scope of each op."""
+    from bench.client import SPANS as CLIENT_SPANS
+    from jax.profiler import ProfileData
+    path = trace.find_xplane(str(trace_dir))
+    planes = list(ProfileData.from_file(path).planes)
+    scopes = op_scopes(path)
+    summary = trace.summarize(planes, CLIENT_SPANS)
+    harvest = [s for s in summary.spans if s.name == "client.harvest"]
+    if len(harvest) >= 2:
+        summary.window_ns = (harvest[0].start_ns, harvest[-1].start_ns)
+    return summary, summarize(planes, summary.window_ns, scopes), planes, \
+        scopes
+
+
 @dataclasses.dataclass
 class _SliceEvent:
     name: str
@@ -433,16 +453,7 @@ def main(argv=None) -> int:
     ap.add_argument("--slice", type=Path, default=None,
                     help="also write two steps' events here (gzip JSON)")
     args = ap.parse_args(argv)
-    from bench.client import SPANS as CLIENT_SPANS
-    from jax.profiler import ProfileData
-    path = trace.find_xplane(args.trace_dir)
-    planes = list(ProfileData.from_file(path).planes)
-    scopes = op_scopes(path)
-    summary = trace.summarize(planes, CLIENT_SPANS)
-    harvest = [s for s in summary.spans if s.name == "client.harvest"]
-    if len(harvest) >= 2:       # the window bench/run.py measures
-        summary.window_ns = (harvest[0].start_ns, harvest[-1].start_ns)
-    program = summarize(planes, summary.window_ns, scopes)
+    summary, program, planes, scopes = load(args.trace_dir)
     print(json.dumps({
         "window_s": summary.window_s,
         "engine_host_ms": program.engine_host_ms(),

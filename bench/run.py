@@ -4,7 +4,10 @@
 
 From the root of a checkout. The cell is a ``workloads`` entry of
 ``BENCHMARK.json``; its configuration, traffic mix, limits and metric
-readers are files under ``bench/`` found by name. A run:
+readers are files under ``bench/`` found by name, and so is the
+configuration's family (``bench/families/``), which gives the program's
+model configuration, the weights, the reference and the work counts.
+A run:
 
 1. turns on JAX's persistent compilation cache at ``<checkout>/.jax_cache``;
 2. makes the weights on the device from ``--seed``;
@@ -33,6 +36,7 @@ import time
 T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
@@ -46,16 +50,13 @@ sys.path.insert(0, str(ROOT))
 
 import jax  # noqa: E402
 
-from bench import check, spec, stats, trace as trace_mod  # noqa: E402
-from bench.client import SPANS, Client  # noqa: E402
+from bench import check, program_trace, spec, stats  # noqa: E402
+from bench.client import Client  # noqa: E402
 from bench.traffic import Traffic, pow2_ceil  # noqa: E402
 
 CACHE_DIR = ROOT / ".jax_cache"
 DRAIN_S = 90.0     # longest wait after the window for requests to finish
 TRACE_DIR = ROOT / ".bench_trace"
-COUNTERS = ("segments", "emitted_tokens", "prefills", "admission_batches",
-            "prefill_dispatches", "ingest_chunks", "prefill_jit_misses",
-            "finite_checks")
 
 
 class CompileCounter:
@@ -79,13 +80,21 @@ class CompileCounter:
 
 
 class RunData:
-    """What a run recorded, as the metric readers see it."""
+    """What a run recorded, as the metric readers see it: the
+    configuration (``conf``), the client's records, the window
+    (``t0``, ``t1``), the window delta of every integer counter of the
+    engine's stats (``counters``), and in a traced run the profiler
+    trace reduced by ``bench/trace.py`` (``trace``) and by
+    ``bench/program_trace.py`` (``program_trace``: the program's own
+    spans and each device op's named scope); both are None untraced."""
 
     def __init__(self, **kw):
         self.__dict__.update(kw)
 
-    def work(self, name: str):
-        return spec.load_module("work", name)
+    def work(self):
+        """The configuration's operation and byte counts
+        (``bench/work/<WORK>.py`` of its family)."""
+        return spec.load_module("work", spec.family(self.conf).WORK)
 
     @property
     def peak(self) -> Dict[str, float]:
@@ -132,8 +141,8 @@ def build_engine(conf: Dict[str, Any], params):
 def warm_lengths(conf: Dict[str, Any], mix: Dict[str, Any]) -> List[int]:
     """Prompt lengths whose admission reaches every program shape the
     mix can: each first-chunk bucket, each continuation-chunk bucket,
-    and (for a growing KV cache) each bucket of prompt length, which
-    sizes the activation snapshot."""
+    and (where the family's decode state grows with the context) each
+    bucket of prompt length, which sizes the activation snapshot."""
     chunk = conf["serving"]["prefill_chunk"]
     pmin, pmax = mix["prompt_tokens"]["min"], mix["prompt_tokens"]["max"]
     lengths = set()
@@ -146,7 +155,7 @@ def warm_lengths(conf: Dict[str, Any], mix: Dict[str, Any]) -> List[int]:
         while r <= pow2_ceil(min(pmax - chunk, chunk)):
             lengths.add(chunk + r)
             r *= 2
-    if conf["attention_backend"] == "softmax":
+    if spec.family(conf).grows_kv(conf):
         w = pow2_ceil(pmin)
         while w <= pow2_ceil(pmax):
             lengths.add(min(w, pmax))
@@ -172,8 +181,11 @@ def warm_up(engine, conf: Dict[str, Any], mix: Dict[str, Any]) -> int:
 
 
 def counters(engine) -> Dict[str, int]:
+    """Every field of the engine's stats (a dataclass) declared ``int``,
+    so that a counter the program adds reaches the readers."""
     st = engine.stats
-    return {k: int(getattr(st, k)) for k in COUNTERS}
+    return {f.name: int(getattr(st, f.name)) for f in dataclasses.fields(st)
+            if f.type in (int, "int")}
 
 
 def measure(cell: spec.Cell, seed: int, seconds: float, traced: bool,
@@ -228,7 +240,7 @@ def measure(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()),
               "memory_peak_bytes": int(mem.get("peak_bytes_in_use", 0))}
-    delta = {k: c1[k] - c0[k] for k in COUNTERS}
+    delta = {k: c1[k] - c0[k] for k in c1}
     delta.update(n_slots=engine.n_slots, segment_len=engine.segment_len,
                  queued_at_open=queued0, queued_at_close=engine.queue_depth())
     log(f"window {t1 - t0:.3f} s; counters {json.dumps(delta)}")
@@ -246,13 +258,9 @@ def measure(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     del client, engine
     gc.collect()
 
-    summary = None
+    summary = program = None
     if traced:
-        summary = trace_mod.load(str(trace_dir), SPANS)
-        # the window runs between the observations that open and close
-        # it; each observation starts a harvest span
-        harvest = [s for s in summary.spans if s.name == "client.harvest"]
-        summary.window_ns = (harvest[0].start_ns, harvest[-1].start_ns)
+        summary, program, _, _ = program_trace.load(trace_dir)
         device["busy_s"] = summary.busy_s()
         device["window_s"] = summary.window_s
         if trace_dir.parent == TRACE_DIR:
@@ -261,7 +269,7 @@ def measure(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     run = RunData(conf=conf, cell=cell.name, records=records, t0=t0, t1=t1,
                   setup_s=setup_s, closed=closed, counters=delta,
                   output_tokens=output_tokens, trace=summary,
-                  device_kind=device["kind"])
+                  program_trace=program, device_kind=device["kind"])
     metrics = {}
     for m in (cell.per_layer if traced else cell.end_to_end):
         value = spec.load_module("metrics", m["name"]).read(run)
@@ -271,8 +279,9 @@ def measure(cell: spec.Cell, seed: int, seconds: float, traced: bool,
               "metrics": metrics, "device": device, "counters": delta,
               "setup_compile": setup_compile}
     if summary is not None:
-        result["breakdown"] = {"device_ops": summary.top_ops(10),
-                               "idle_gaps": summary.idle_gaps(10)}
+        result["breakdown"] = {
+            "device_ops": summary.top_ops(10),
+            "idle_gaps": program_trace.labelled_gaps(summary, program, 10)}
     return result, params, records
 
 

@@ -1,5 +1,6 @@
-"""Find a cell's pieces by name: its configuration, traffic mix, limits
-and metric readers, each in a file of its own under ``bench/``.
+"""Find a cell's pieces by name: its configuration and the
+configuration's family, traffic mix, limits and metric readers, each in
+a file of its own under ``bench/``.
 
 A cell is one ``workloads`` entry of ``BENCHMARK.json``. Nothing here
 knows a cell, a configuration or a metric by name: adding one means
@@ -9,6 +10,7 @@ adding files and an entry, not editing this module.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -61,42 +63,32 @@ def load_cell(name: str, benchmark: Optional[Path] = None) -> Cell:
 
 
 def load_module(kind: str, name: str):
-    """``bench/<kind>/<name>.py`` as a module (names may hold dots)."""
+    """``bench/<kind>/<name>.py`` as a module (names may hold dots),
+    executed once per process, as an import would be: a reference's
+    jitted functions then compile once, not once per comparison."""
     path = BENCH_DIR / kind / f"{name}.py"
     if not path.exists():
         raise FileNotFoundError(f"no {kind} module for {name!r} at {path}")
+    return _exec(path)
+
+
+@functools.lru_cache(maxsize=None)
+def _exec(path: Path):
     mod_spec = importlib.util.spec_from_file_location(
-        f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
+        f"bench_{path.parent.name}_"
+        f"{path.stem.replace('.', '_').replace('-', '_')}", path)
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
     return mod
 
 
+def family(conf: Dict[str, Any]):
+    """The module of the configuration's family,
+    ``bench/families/<family>.py`` (``"dense"`` where the file names
+    none); ``bench/families/__init__.py`` gives what it defines."""
+    return load_module("families", conf.get("family", "dense"))
+
+
 def model_config(conf: Dict[str, Any]):
-    """The program's ``ModelConfig`` for a configuration file written
-    with the published ``config.json`` keys (Qwen3 dense layout)."""
-    from repro.configs.base import ModelConfig
-    if conf.get("hidden_act", "silu") != "silu":
-        raise ValueError(f"hidden_act {conf['hidden_act']!r}: the program "
-                         "serves swiglu MLPs only")
-    if float(conf.get("rms_norm_eps", 1e-6)) != 1e-6:
-        raise ValueError("the program's rmsnorm uses eps 1e-6")
-    dtype = conf.get("torch_dtype", "bfloat16")
-    return ModelConfig(
-        name=f"{conf['model_type']}-{conf['attention_backend']}",
-        family="dense",
-        n_layers=conf["num_hidden_layers"],
-        d_model=conf["hidden_size"],
-        n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf["num_key_value_heads"],
-        head_dim=conf["head_dim"],
-        d_ff=conf["intermediate_size"],
-        vocab_size=conf["vocab_size"],
-        qk_norm=conf["model_type"] == "qwen3",
-        rope_theta=float(conf["rope_theta"]),
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        attention_backend=conf["attention_backend"],
-        decode_kernel=conf.get("decode_kernel", "auto"),
-        dtype=dtype,
-        param_dtype=dtype,
-    )
+    """The program's ``ModelConfig`` for a configuration file."""
+    return family(conf).model_config(conf)

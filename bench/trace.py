@@ -219,9 +219,3 @@ def summarize(planes, span_names: Sequence[str],
             raise ValueError("no client span in the trace")
         window_ns = (spans[0].start_ns, max(e.end_ns for e in spans))
     return TraceSummary(window_ns=window_ns, devices=devices, spans=spans)
-
-
-def load(trace_dir: str, span_names: Sequence[str]) -> TraceSummary:
-    from jax.profiler import ProfileData
-    pd = ProfileData.from_file(find_xplane(trace_dir))
-    return summarize(pd.planes, span_names)
